@@ -14,6 +14,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 import jax                                                    # noqa: E402
 import jax.numpy as jnp                                       # noqa: E402
 
+from repro.compile_cache import enable_compile_cache  # noqa: E402
 from repro.configs.chase_laion import (ChaseBenchConfig, bench_config,
                                        smoke_bench_config)    # noqa: E402
 from repro.core import Metric                                 # noqa: E402
@@ -21,6 +22,9 @@ from repro.data import make_laion_catalog                     # noqa: E402
 from repro.index import FlatIndex, build_ivf                  # noqa: E402
 
 SELECTIVITIES = (1.0, 0.9, 0.7, 0.5, 0.3, 0.03)
+
+# every benchmark imports this module before it compiles anything
+enable_compile_cache()
 
 
 @dataclasses.dataclass

@@ -19,8 +19,9 @@ shards=1 rows gate fresh QPS within tolerance of the committed baseline;
 multi-shard rows are tracked, not gated — on a CPU host the "devices" share
 one socket, so shard scaling measures collective overhead, not speedup).
 
-The sweep runs in a child process so the fake-device topology exists no
-matter how the harness was launched:
+On CPU the sweep runs in a child process so the fake-device topology exists
+no matter how the harness was launched; on an accelerator it runs in the
+harness's own process, which holds the chips:
 
   PYTHONPATH=src python -m benchmarks.q10_sharded_qps [--full]
 """
@@ -55,8 +56,9 @@ def _queries(base, q: int):
     return (qs + 0.01 * rng.standard_normal(qs.shape)).astype(np.float32)
 
 
-def _child(n_rows: int, dim: int, k: int, seed: int) -> dict:
-    """The measured sweep (runs under the fake-device topology)."""
+def _child(n_rows: int, dim: int, k: int, seed: int,
+           shards_sweep: tuple = SHARDS) -> dict:
+    """The measured sweep (fake CPU devices, or the real chips in-process)."""
     import numpy as np
     from repro.api import connect
     from repro.core import EngineOptions
@@ -74,13 +76,13 @@ def _child(n_rows: int, dim: int, k: int, seed: int) -> dict:
     ref_stmt = connect(cat, flat).prepare(sql)
 
     report = {"n_rows": n_rows, "dim": dim, "k": k,
-              "device_count": DEVICE_COUNT, "batches": list(BATCHES),
+              "device_count": max(shards_sweep), "batches": list(BATCHES),
               "workloads": {"sharded": []},
               "parity": {"shards1_bitparity": False,
                          "counter_exact_shards": []}}
     entries = report["workloads"]["sharded"]
     base_qps: dict[int, float] = {}
-    for shards in SHARDS:
+    for shards in shards_sweep:
         db = connect(cat, EngineOptions(
             engine="brute", use_pallas=True,
             dist=DistSpec(mesh_shape=(shards,))))
@@ -131,16 +133,27 @@ def _child(n_rows: int, dim: int, k: int, seed: int) -> dict:
 
 
 def run(env, rows: list) -> dict:
-    """Harness entry: spawn the sweep under fake CPU devices, collect rows.
+    """Harness entry: run the sweep, collect rows.
 
-    A child process is required because the fake-device count must be set
-    before jax initializes — the parent harness already booted jax on the
-    real (1-device) topology."""
+    On CPU the sweep runs in a child process, because the fake-device count
+    must be set before jax initializes — the parent harness already booted
+    jax on the real (1-device) topology.  On an accelerator it runs in THIS
+    process over the devices it already holds (a child could not reach
+    them), at the shard counts the host has."""
+    import jax
+
     from .common import Row
 
+    rows_n = min(env.cfg.n_rows, FLAT_ROWS)
+    k = min(env.cfg.k_top, 10)
+    if jax.default_backend() != "cpu":
+        sweep = tuple(s for s in SHARDS if s <= jax.device_count())
+        report = _child(rows_n, env.cfg.dim, k, env.cfg.seed, sweep)
+        with open(OUT_JSON, "w") as f:
+            json.dump(report, f, indent=2)
+        return _collect(report, rows, Row)
     cmd = [sys.executable, "-m", "benchmarks.q10_sharded_qps", "--child",
-           "--rows", str(min(env.cfg.n_rows, FLAT_ROWS)),
-           "--dim", str(env.cfg.dim), "--k", str(min(env.cfg.k_top, 10)),
+           "--rows", str(rows_n), "--dim", str(env.cfg.dim), "--k", str(k),
            "--seed", str(env.cfg.seed)]
     child_env = dict(os.environ)
     child_env["XLA_FLAGS"] = (
@@ -154,10 +167,14 @@ def run(env, rows: list) -> dict:
         raise RuntimeError(f"q10 child failed:\n{r.stdout}\n{r.stderr}")
     with open(OUT_JSON) as f:
         report = json.load(f)
+    return _collect(report, rows, Row)
+
+
+def _collect(report: dict, rows: list, row_cls) -> dict:
     for e in report["workloads"]["sharded"]:
-        rows.append(Row(f"q10_s{e['shards']}_b{e['batch']}", e["ms"],
-                        **{kk: vv for kk, vv in e.items()
-                           if kk not in ("ms",)}))
+        rows.append(row_cls(f"q10_s{e['shards']}_b{e['batch']}", e["ms"],
+                            **{kk: vv for kk, vv in e.items()
+                               if kk not in ("ms",)}))
     return report
 
 

@@ -176,6 +176,17 @@ def run(env: BenchEnv, rows: list, batches=BATCHES) -> dict:
 
     report["speedup_b64"] = {m: round(b64(m) / b64("fp32"), 2)
                              for m in MODES if m != "fp32"}
+    # share of the batches whose top-k certificate failed, so that they
+    # ran the fp32 kernel too (DESIGN.md §13): one bucketed execution of
+    # each timed batch, which the executor counts
+    report["fp32_fallback_share"] = {}
+    for mode in MODES:
+        if mode != "fp32":
+            for b in batches:
+                compiled[mode].execute_bucketed(qv=_queries(qvecs, b))
+            counts = compiled[mode].executor.quant_topk
+            report["fp32_fallback_share"][mode] = (counts["fp32_fallbacks"]
+                                                   / counts["batches"])
     with open(OUT_JSON, "w") as f:
         json.dump(report, f, indent=2)
     return report

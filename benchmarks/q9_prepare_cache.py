@@ -8,7 +8,7 @@ only.  This bench measures that gap on the session API
 variants:
 
 * ``prepare_cold``   — first-ever prepare of Q1 (full compile, includes the
-  first execute's jit),
+  first execute's jit; JAX's persistent compilation cache is off for it),
 * ``prepare_warm``   — re-prepare of the *same text* (cache hit),
 * ``prepare_variant``— re-prepare of a whitespace + param-renamed +
   conjunct-reordered variant (MUST also hit: zero new executables,
@@ -16,11 +16,13 @@ variants:
 * ``execute_hit``    — a bucketed batch execute through a variant statement
   (rename translation on the hot path, reusing the original's bucket
   executable),
-* ``restart_cold`` / ``restart_warm`` — SUBPROCESS prepare + first batch
+* ``restart_cold`` / ``restart_warm`` — restart prepare + first batch
   execute latency, without vs with a populated persistent AOT plan cache
-  (DESIGN.md §15): three children run back-to-back (cold, untimed
+  (DESIGN.md §15): three restarts run back-to-back (cold, untimed
   populate, warm), so the ``restart.speedup`` ratio never rides cross-run
-  machine noise.  The warm child hard-asserts zero retraces.
+  machine noise.  Each restart is emulated in this process (in-memory
+  executables dropped, a fresh session), so one process holds the
+  accelerator throughout.  The warm restart hard-asserts zero retraces.
   ``scripts/bench_gate.py`` gates ``speedup >= 10``.
 
 Writes ``BENCH_api.json``.
@@ -29,10 +31,9 @@ Standalone:  PYTHONPATH=src python -m benchmarks.q9_prepare_cache [--full]
 """
 from __future__ import annotations
 
+import contextlib
 import json
 import os
-import subprocess
-import sys
 import tempfile
 import time
 
@@ -61,69 +62,63 @@ LIMIT 10
 """
 
 
-CHILD_MARK = "Q9_CHILD_JSON:"
-
-
-def _child_binds(env: BenchEnv) -> list:
+def _restart_binds(env: BenchEnv) -> list:
     return [{"qv": env.qvecs[i % len(env.qvecs)],
              "max_price": env.price_thresholds[0.5], "mid": 0}
             for i in range(N_BATCH)]
 
 
-def child_main(role: str, aot_dir: str, full: bool) -> None:
-    """Subprocess body: build the seeded env (untimed), then time ONE
-    prepare + first batch execute — the restart cost a serving process
-    actually pays.  ``cold`` runs without a cache; ``populate`` / ``warm``
-    attach ``aot_dir`` (DESIGN.md §15).  The warm child hard-asserts zero
-    retraces: if the persistent cache misses, the bench fails loud."""
+@contextlib.contextmanager
+def _no_compile_cache():
+    """Keep JAX's persistent compilation cache out of the cold-prepare and
+    restart rows: a "cold" prepare that found its XLA executables on disk
+    would not be cold (the restart rows measure the persistent AOT plan
+    cache, not JAX's)."""
+    import jax
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", prev)
+
+
+def _restart(role: str, aot_dir: str, env: BenchEnv) -> dict:
+    """Time ONE restart's prepare + first batch execute — the restart cost
+    a serving process actually pays.  The restart is emulated in this
+    process: every in-memory executable is dropped (``jax.clear_caches``)
+    and the session connects afresh, so one process keeps the accelerator
+    (cross-process AOT restarts are covered by ``tests/test_aot_cache.py``).
+    ``cold`` runs without a cache; ``populate`` / ``warm`` attach
+    ``aot_dir`` (DESIGN.md §15).  The warm role hard-asserts zero retraces:
+    if the persistent cache misses, the bench fails loud."""
     import jax
 
-    from .common import get_env
-    env = get_env(smoke=not full)
+    jax.clear_caches()
     db = connect(env.catalog,
                  EngineOptions(engine="chase", probe=env.cfg.probe),
                  aot_cache_path=(None if role == "cold" else aot_dir))
-    binds = _child_binds(env)
-    t0 = time.perf_counter()
-    stmt = db.prepare(SQL)
-    out = stmt.execute(binds)
-    jax.block_until_ready(out["ids"])
-    ms = 1e3 * (time.perf_counter() - t0)
+    binds = _restart_binds(env)
+    with _no_compile_cache():
+        t0 = time.perf_counter()
+        stmt = db.prepare(SQL)
+        out = stmt.execute(binds)
+        jax.block_until_ready(out["ids"])
+        ms = 1e3 * (time.perf_counter() - t0)
     traces = sum(stmt.executor.trace_counts.values())
     if role == "warm" and traces:
         raise SystemExit(f"warm restart retraced ({traces} traces) — the "
                          f"persistent AOT cache missed")
-    print(CHILD_MARK + json.dumps({"role": role, "ms": round(ms, 3),
-                                   "traces": traces}))
-
-
-def _spawn(role: str, aot_dir: str, full: bool) -> dict:
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    child_env = dict(os.environ)
-    child_env["PYTHONPATH"] = (os.path.join(repo, "src") + os.pathsep
-                               + child_env.get("PYTHONPATH", ""))
-    cmd = [sys.executable, "-m", "benchmarks.q9_prepare_cache",
-           "--child", role, "--aot", aot_dir] + (["--full"] if full else [])
-    proc = subprocess.run(cmd, cwd=repo, env=child_env,
-                          capture_output=True, text=True, timeout=1200)
-    if proc.returncode != 0:
-        raise RuntimeError(f"q9 restart child {role!r} failed:\n"
-                           f"{proc.stdout}\n{proc.stderr}")
-    for line in proc.stdout.splitlines():
-        if line.startswith(CHILD_MARK):
-            return json.loads(line[len(CHILD_MARK):])
-    raise RuntimeError(f"q9 restart child {role!r} printed no result line")
+    return {"role": role, "ms": round(ms, 3), "traces": traces}
 
 
 def restart_bench(env: BenchEnv, rows: list) -> dict:
-    """Cold vs AOT-warm restart latency: three subprocesses back-to-back
+    """Cold vs AOT-warm restart latency: three restarts back-to-back
     (cold, untimed populate, warm) over one temporary cache dir."""
-    from repro.configs.chase_laion import smoke_bench_config
-    full = env.cfg.n_rows != smoke_bench_config().n_rows
     with tempfile.TemporaryDirectory(prefix="q9aot-") as aot_dir:
-        cold = _spawn("cold", aot_dir, full)
-        _spawn("populate", aot_dir, full)      # untimed: persists entries
-        warm = _spawn("warm", aot_dir, full)
+        cold = _restart("cold", aot_dir, env)
+        _restart("populate", aot_dir, env)     # untimed: persists entries
+        warm = _restart("warm", aot_dir, env)
     speedup = cold["ms"] / max(warm["ms"], 1e-6)
     rows.append(Row("q9_restart_cold", cold["ms"]))
     rows.append(Row("q9_restart_warm", warm["ms"],
@@ -153,11 +148,12 @@ def run(env: BenchEnv, rows: list) -> dict:
                     "cap": env.price_thresholds[0.5], "m": 0}
                    for i in range(N_BATCH)]
 
-    t0 = time.perf_counter()
-    stmt = db.prepare(SQL)
-    out = stmt.execute(binds)
-    jax.block_until_ready(out["ids"])
-    cold_ms = 1e3 * (time.perf_counter() - t0)
+    with _no_compile_cache():
+        t0 = time.perf_counter()
+        stmt = db.prepare(SQL)
+        out = stmt.execute(binds)
+        jax.block_until_ready(out["ids"])
+        cold_ms = 1e3 * (time.perf_counter() - t0)
 
     warm_ms = _timed_ms(lambda: db.prepare(SQL))
     variant_ms = _timed_ms(lambda: db.prepare(SQL_VARIANT))
@@ -205,14 +201,7 @@ if __name__ == "__main__":
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true",
                     help="full-scale catalog (default: smoke)")
-    ap.add_argument("--child", choices=("cold", "populate", "warm"),
-                    help="restart-bench subprocess role (internal)")
-    ap.add_argument("--aot", default="",
-                    help="AOT cache dir for --child populate/warm")
     args = ap.parse_args()
-    if args.child:
-        child_main(args.child, args.aot, args.full)
-        raise SystemExit(0)
     env = get_env(smoke=not args.full)
     rows: list[Row] = []
     report = run(env, rows)

@@ -24,6 +24,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.schema import Metric
 from repro.dist.collectives import (distributed_range, distributed_topk,
                                     shard_corpus)
@@ -90,5 +91,6 @@ def main_batched():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()
     main_batched()

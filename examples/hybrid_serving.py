@@ -20,6 +20,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.models import init_params
 from repro.serving.decode import generate
@@ -85,4 +86,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

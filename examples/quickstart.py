@@ -19,6 +19,7 @@ import numpy as np
 import jax
 
 from repro.api import ExecutionHints, connect
+from repro.compile_cache import enable_compile_cache
 from repro.core import EngineOptions, Metric, compile_query
 from repro.data import make_laion_catalog, selectivity_threshold
 from repro.index import build_ivf
@@ -112,4 +113,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -17,6 +17,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 import jax
 
 from repro.checkpoint import Checkpointer, latest_step, restore
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.data.pipeline import DataConfig, SyntheticLM
 from repro.models import init_params
@@ -76,4 +77,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -44,9 +44,9 @@ Rows gated:
     measured back-to-back in one run); the single-table drift row's
     thinner margin is tracked, not gated.
   * BENCH_api.json:   q9 restart row — within-run contract only: the
-    AOT-warm subprocess (prepare + first batch execute against a populated
+    AOT-warm restart (prepare + first batch execute against a populated
     persistent plan cache, DESIGN.md §15) must be >= 10x faster than the
-    cold subprocess compile, both spawned back-to-back by one q9 run.
+    cold restart compile, both emulated back-to-back by one q9 run.
   * BENCH_quant.json: flat quantized-scan rows (key: batch, qps) — the
     same interpret-mode fused-kernel stability argument as BENCH_batch,
     per mode (fp32 / bf16 / int8).  Two gates: fresh-vs-committed QPS per
@@ -247,9 +247,10 @@ def main() -> int:
                 f"{f32:.1f} (same-run ratio {i8 / f32:.2f}x)")
 
     # within-run restart contract (BENCH_api.json): preparing a persisted
-    # statement in a FRESH process must be >= 10x faster than the cold
-    # subprocess compile — cold and AOT-warm children run back-to-back in
-    # one q9 invocation, so the ratio never rides cross-run machine noise
+    # statement after a restart (in-memory executables dropped) must be
+    # >= 10x faster than the cold compile — cold and AOT-warm restarts run
+    # back-to-back in one q9 invocation, so the ratio never rides
+    # cross-run machine noise
     restart = ((_fresh("BENCH_api.json") or _committed("BENCH_api.json"))
                or {}).get("restart")
     if restart and restart.get("speedup") is not None:

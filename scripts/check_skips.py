@@ -21,7 +21,7 @@ import xml.etree.ElementTree as ET
 # message matches none of these fails the smoke.
 REGISTERED_REASONS = [
     r"hypothesis not installed in this container",
-    r"no TPU backend attached",
+    r"no v5e:2x2 topology can be described here",
 ]
 
 

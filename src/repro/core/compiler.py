@@ -207,6 +207,21 @@ def _pad_leading(v, bucket: int) -> np.ndarray:
         [v, np.broadcast_to(v[-1:], (pad,) + v.shape[1:])])
 
 
+FALLBACK_STAT = "fp32_fallback"
+
+
+def _without_fallback(out):
+    """``out`` without the quantized top-k lane's per-query
+    ``fp32_fallback`` stat (traced or on the host), so that every surface
+    returns the same output tree in every lane; the bucketed executor
+    counts the flag first (:meth:`BucketedExecutor.note_fallback`)."""
+    stats = out.get("stats") if isinstance(out, dict) else None
+    if not isinstance(stats, dict) or FALLBACK_STAT not in stats:
+        return out
+    return {**out, "stats": {k: v for k, v in stats.items()
+                             if k != FALLBACK_STAT}}
+
+
 class BucketedExecutor:
     """Lazy per-(plan, bucket) executor cache — the serving execution tier.
 
@@ -229,6 +244,20 @@ class BucketedExecutor:
         self._aot = None
         self._aot_exec: dict[tuple[int, str], Any] = {}
         self.aot_loaded: dict[int, int] = {}
+        # quantized top-k lane (DESIGN.md §13): executions, and those whose
+        # certificate failed so that the batch ran the fp32 kernel as well
+        self.quant_topk = {"batches": 0, "fp32_fallbacks": 0}
+
+    def note_fallback(self, out):
+        """Count a bucketed execution of the quantized top-k lane in
+        :attr:`quant_topk` (reading its ``fp32_fallback`` flag on the host)
+        and return the output tree without the flag."""
+        stats = out.get("stats") if isinstance(out, dict) else None
+        if isinstance(stats, dict) and FALLBACK_STAT in stats:
+            fell_back = bool(np.asarray(stats[FALLBACK_STAT]).any())
+            self.quant_topk["batches"] += 1
+            self.quant_topk["fp32_fallbacks"] += int(fell_back)
+        return _without_fallback(out)
 
     def attach_aot(self, binding) -> None:
         """Route this executor through a persistent AOT plan cache
@@ -282,7 +311,7 @@ class BucketedExecutor:
             out = self._aot_call(bucket, args)
         else:
             out = self.executable(bucket)(*args)
-        return out, bucket, valid
+        return self.note_fallback(out), bucket, valid
 
     # -- persistent AOT plan cache (DESIGN.md §15) --------------------------
 
@@ -571,7 +600,7 @@ class CompiledQuery:
 
         def flat(lvs, _td=treedef):
             arrays, b = jax.tree.unflatten(_td, lvs)
-            return self.plan.batch_fn(arrays, b)
+            return _without_fallback(self.plan.batch_fn(arrays, b))
 
         return _aot.export_flat(flat, leaves).serialize()
 
@@ -869,7 +898,7 @@ def _single_via_batch(bfn: Callable) -> Callable:
 
     def fn(arrays, binds):
         stacked = {k: jnp.asarray(v)[None] for k, v in binds.items()}
-        out = bfn(arrays, stacked)
+        out = _without_fallback(bfn(arrays, stacked))
         return jax.tree.map(lambda v: v[0], out)
 
     return fn
@@ -930,6 +959,8 @@ def compile_plan(sql: str, plan: PlanNode, catalog: Catalog,
     # register a sharded handle (a version bump this plan must not see as
     # staleness on its first execute)
     dep_keys = _catalog_dep_keys(a, catalog, options)
-    return CompiledQuery(compiled_plan, jax.jit(fn), arrays, jax.jit(bfn),
+    return CompiledQuery(compiled_plan, jax.jit(fn), arrays,
+                         jax.jit(lambda arrs, binds: _without_fallback(
+                             bfn(arrs, binds))),
                          executor, _catalog=catalog, _dep_keys=dep_keys,
                          _bound_versions=catalog.version_snapshot(dep_keys))

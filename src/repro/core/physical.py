@@ -251,17 +251,31 @@ def _flat_topk_batch(opts: EngineOptions, arrays, metric: Metric, corpus,
     """Fused flat batched top-k; routes through the quantized lowering
     (DESIGN.md §13) when ``EngineOptions.quant`` is set — the quantized
     twin's arrays ride the plan's ``arrays`` dict (``qvecs``/``qscales``),
-    so Catalog re-registrations re-bind with zero retraces."""
+    so Catalog re-registrations re-bind with zero retraces.  Returns (ids,
+    sims, valid, stats); the quantized lane's stats add ``fp32_fallback``,
+    1 for each query whose batch failed the top-k certificate and ran the
+    fp32 kernel as well (the bucketed executor counts it in
+    ``BucketedExecutor.quant_topk``; every surface drops it from the
+    output tree)."""
+    m, n = qs.shape[0], corpus.shape[0]
+    stats = {"probes": jnp.zeros((m,), jnp.int32),
+             "distance_evals": _flat_evals(qvalid, m, n)}
     if opts.quant is not None:
         from ..kernels.quant import fused_scan_topk_batch_q
-        return fused_scan_topk_batch_q(
-            corpus, arrays["qvecs"], arrays["qscales"], qs, k, row_mask,
-            metric, rescore_factor=opts.rescore_factor,
+        ids, sims, valid, fallback = fused_scan_topk_batch_q(
+            corpus, arrays["qvecs"], arrays["qscales"], arrays["qhalf"],
+            arrays["ql1"], arrays["ql2"], qs, k, row_mask, metric,
+            rescore_factor=opts.rescore_factor,
             interpret=opts.interpret_pallas, qvalid=qvalid)
+        fb = jnp.full((m,), fallback, jnp.int32)
+        stats["fp32_fallback"] = fb if qvalid is None else jnp.where(
+            qvalid, fb, 0)
+        return ids, sims, valid, stats
     from ..kernels.ops import fused_scan_topk_batch
-    return fused_scan_topk_batch(corpus, qs, k, row_mask, metric,
-                                 interpret=opts.interpret_pallas,
-                                 qvalid=qvalid)
+    ids, sims, valid = fused_scan_topk_batch(corpus, qs, k, row_mask, metric,
+                                             interpret=opts.interpret_pallas,
+                                             qvalid=qvalid)
+    return ids, sims, valid, stats
 
 
 def _flat_evals(qvalid, m: int, n: int) -> jnp.ndarray:
@@ -434,8 +448,9 @@ def _dist_topk_core(opts: EngineOptions, metric: Metric, k: int,
         qv = _dist_qvalid(qvalid, qn)
         if opts.quant is not None:
             ids, sims, valid = dfn(arrays["dcorpus"], arrays["dqvecs"],
-                                   arrays["dqscales"], arrays["drow_ids"],
-                                   qs, mask, qv)
+                                   arrays["dqscales"], arrays["dqhalf"],
+                                   arrays["dql1"], arrays["dql2"],
+                                   arrays["drow_ids"], qs, mask, qv)
         else:
             ids, sims, valid = dfn(arrays["dcorpus"], arrays["drow_ids"], qs,
                                    mask, qv)
@@ -958,7 +973,7 @@ def _build_dist_join_perleft(a: Analysis, catalog: Catalog,
                     count = jnp.sum(valid)
             else:
                 if opts.use_pallas:
-                    # single-query kernel per left row: the matvec-shaped
+                    # one kernel launch per left row: the per-query
                     # baseline the query-tiled lowering replaces
                     from ..kernels.ops import fused_range_scan
                     hit, raw, _cnt = fused_range_scan(
@@ -1033,22 +1048,21 @@ def _knn_join_core(a: Analysis, catalog: Catalog, opts: EngineOptions,
                              0.0)
             stats = {"probes": jnp.zeros((m,), jnp.int32),
                      "distance_evals": _flat_evals(qvalid, m, n)}
-        else:  # brute (compiled top-k; LingoDB-V-like)
-            if opts.use_pallas:
-                ids, sims, valid = _flat_topk_batch(
-                    opts, arrays, metric, corpus, qs, k, rm, qvalid=qvalid)
+        elif opts.use_pallas:  # brute (compiled top-k; LingoDB-V-like)
+            ids, sims, valid, stats = _flat_topk_batch(
+                opts, arrays, metric, corpus, qs, k, rm, qvalid=qvalid)
+        else:
+            flat = FlatIndex(metric, corpus)
+            if rm is None:
+                ids, sims, valid = jax.vmap(
+                    lambda q: flat.topk(q, k, None))(qs)
             else:
-                flat = FlatIndex(metric, corpus)
-                if rm is None:
-                    ids, sims, valid = jax.vmap(
-                        lambda q: flat.topk(q, k, None))(qs)
-                else:
-                    ids, sims, valid = jax.vmap(
-                        lambda q, r: flat.topk(q, k, r))(qs, rm)
-                if qvalid is not None:
-                    valid = valid & qvalid[:, None]
-                    ids = jnp.where(valid, ids, -1)
-                    sims = jnp.where(valid, sims, 0.0)
+                ids, sims, valid = jax.vmap(
+                    lambda q, r: flat.topk(q, k, r))(qs, rm)
+            if qvalid is not None:
+                valid = valid & qvalid[:, None]
+                ids = jnp.where(valid, ids, -1)
+                sims = jnp.where(valid, sims, 0.0)
             stats = {"probes": jnp.zeros((m,), jnp.int32),
                      "distance_evals": _flat_evals(qvalid, m, n)}
         if live:
@@ -1638,39 +1652,24 @@ def build_vknn_sf_batch(a: Analysis, catalog: Catalog, opts: EngineOptions,
             else:
                 ids, sims, valid = jax.vmap(post)(
                     ids_o, sims_o, valid_o, _as_per_query(row_mask, qn))
-        else:  # brute (LingoDB-V analogue) or missing index
-            if (opts.use_pallas and opts.quant is None and qn == 1
-                    and qvalid is None
-                    and (row_mask is None or row_mask.ndim == 1)):
-                # single-query fast path: plans routed through
-                # _single_via_batch (live/dist/quant singles) share the
-                # 1-D validity-lane single kernel instead of paying the
-                # batched kernel's BLOCK_Q=8 pad + (Q, N) mask broadcast
-                # — the q12 b1 live-scan overhead (bench_gate gates it)
-                from ..kernels.ops import fused_scan_topk
-                i1, s1, v1 = fused_scan_topk(
-                    corpus, qs[0], k, row_mask, metric,
-                    interpret=opts.interpret_pallas)
-                ids, sims, valid = i1[None], s1[None], v1[None]
-            elif opts.use_pallas:
-                ids, sims, valid = _flat_topk_batch(
-                    opts, arrays, metric, corpus, qs, k, row_mask,
-                    qvalid=qvalid)
+        elif opts.use_pallas:  # brute (LingoDB-V analogue) or missing index
+            ids, sims, valid, stats = _flat_topk_batch(
+                opts, arrays, metric, corpus, qs, k, row_mask, qvalid=qvalid)
+        else:
+            flat = FlatIndex(metric, corpus)
+            if row_mask is None:
+                ids, sims, valid = jax.vmap(
+                    lambda q: flat.topk(q, k, None))(qs)
+            elif row_mask.ndim == 1:                # shared live validity lane
+                ids, sims, valid = jax.vmap(
+                    lambda q: flat.topk(q, k, row_mask))(qs)
             else:
-                flat = FlatIndex(metric, corpus)
-                if row_mask is None:
-                    ids, sims, valid = jax.vmap(
-                        lambda q: flat.topk(q, k, None))(qs)
-                elif row_mask.ndim == 1:            # shared live validity lane
-                    ids, sims, valid = jax.vmap(
-                        lambda q: flat.topk(q, k, row_mask))(qs)
-                else:
-                    ids, sims, valid = jax.vmap(
-                        lambda q, rm: flat.topk(q, k, rm))(qs, row_mask)
-                if qvalid is not None:
-                    valid = valid & qvalid[:, None]
-                    ids = jnp.where(valid, ids, -1)
-                    sims = jnp.where(valid, sims, 0.0)
+                ids, sims, valid = jax.vmap(
+                    lambda q, rm: flat.topk(q, k, rm))(qs, row_mask)
+            if qvalid is not None:
+                valid = valid & qvalid[:, None]
+                ids = jnp.where(valid, ids, -1)
+                sims = jnp.where(valid, sims, 0.0)
             stats = {"probes": jnp.zeros((qn,), jnp.int32),
                      "distance_evals": _flat_evals(qvalid, qn, n)}
         if live:

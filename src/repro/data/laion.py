@@ -93,6 +93,9 @@ def make_laion_catalog(n_rows: int = 100_000, n_queries: int = 100,
         "vec": vector_col(dim, metric),
         "embedding": vector_col(dim, metric),
     }, primary_key="sample_id")
+    # one device buffer behind both vector column names: the aliases are
+    # the same embedding, and a second copy would double the corpus's HBM
+    dvec = jnp.asarray(vec)
     laion = Table(laion_schema, {
         "sample_id": jnp.arange(n_rows, dtype=jnp.int64),
         "height": jnp.asarray(height), "width": jnp.asarray(width),
@@ -103,8 +106,8 @@ def make_laion_catalog(n_rows: int = 100_000, n_queries: int = 100,
         "cuisine": jnp.asarray(cuisine),
         "rating": jnp.asarray(rating),
         "release_year": jnp.asarray(release_year),
-        "vec": jnp.asarray(vec),
-        "embedding": jnp.asarray(vec),
+        "vec": dvec,
+        "embedding": dvec,
     })
 
     q_pref_rating = rng.integers(0, 5, size=n_queries).astype(np.int32)
@@ -120,14 +123,15 @@ def make_laion_catalog(n_rows: int = 100_000, n_queries: int = 100,
         "embedding": vector_col(dim, metric),
         "vec": vector_col(dim, metric),
     }, primary_key="id")
+    dqvec = jnp.asarray(qvec)
     queries = Table(queries_schema, {
         "id": jnp.arange(n_queries, dtype=jnp.int64),
         "preferred_rating": jnp.asarray(q_pref_rating),
         "preferred_release_year": jnp.asarray(q_pref_year),
         "cuisine": jnp.asarray(q_cuisine),
         "capture_date": jnp.asarray(q_capture),
-        "embedding": jnp.asarray(qvec),
-        "vec": jnp.asarray(qvec),
+        "embedding": dqvec,
+        "vec": dqvec,
     })
 
     cat = Catalog()

@@ -31,11 +31,11 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from ..core.expr import distance_values, in_range, order_key
 from ..core.schema import Metric
 from ..index.flat import masked_topk
+from ..kernels.ops import best_first
 
 
 def shard_corpus(mesh: Mesh, corpus: jnp.ndarray,
@@ -74,11 +74,11 @@ def distributed_topk(mesh: Mesh, metric: Metric, k: int,
                          0.0)
         return jnp.where(valid, sel_ids, -1), sims, valid
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(axes, None), P(axes), P(), P(axes)),
         out_specs=(P(), P(), P()),
-        check_rep=False)
+        check_vma=False)
 
 
 def distributed_range(mesh: Mesh, metric: Metric, capacity: int,
@@ -107,11 +107,11 @@ def distributed_range(mesh: Mesh, metric: Metric, capacity: int,
         return (jnp.where(valid, sel_ids, -1), sims, valid,
                 jnp.sum(count))
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(axes, None), P(axes), P(), P(), P(axes)),
         out_specs=(P(), P(), P(), P()),
-        check_rep=False)
+        check_vma=False)
 
 
 # ---------------------------------------------------------------------------
@@ -167,9 +167,7 @@ def _merge_topk(metric: Metric, keys: jnp.ndarray, gids: jnp.ndarray,
         # clamp per level: an early level's gathered width can undercut k
         # when per-shard buffers are capacity-starved (keeping everything is
         # lossless; later levels widen back past k — see the range merge)
-        neg, idx = jax.lax.top_k(-keys, min(k, keys.shape[1]))  # row-wise
-        keys = -neg
-        gids = jnp.take_along_axis(gids, idx, axis=1)
+        keys, gids = best_first(keys, gids, min(k, keys.shape[1]))
     valid = jnp.isfinite(keys)
     sims = jnp.where(valid, -keys if metric.is_similarity() else keys, 0.0)
     return jnp.where(valid, gids, -1), sims, valid
@@ -222,12 +220,12 @@ def distributed_topk_batch(mesh: Mesh, metric: Metric, k: int,
         keys = jnp.where(lvalid, order_key(metric, lsims), jnp.inf)
         return _merge_topk(metric, keys, gids, k, axes)
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(axes, None), P(axes), P(None, None),
                   _mask_spec(axes, per_query_mask), P(None)),
         out_specs=(P(None, None), P(None, None), P(None, None)),
-        check_rep=False)
+        check_vma=False)
 
 
 def distributed_topk_batch_q(mesh: Mesh, metric: Metric, k: int,
@@ -247,27 +245,28 @@ def distributed_topk_batch_q(mesh: Mesh, metric: Metric, k: int,
     per-device HBM corpus stream.
 
     Returns a ``shard_map``'d callable ``fn(sh_corpus, sh_qvecs,
-    sh_scales, sh_ids, qs, sh_mask, qvalid) -> (ids, sims, valid)`` with
-    ``sh_qvecs``/``sh_scales`` the row-sharded
+    sh_scales, sh_half, sh_l1, sh_l2, sh_ids, qs, sh_mask, qvalid) ->
+    (ids, sims, valid)`` with ``sh_qvecs`` … ``sh_l2`` the row-sharded
     :class:`~repro.data.quantized.QuantizedCorpus` arrays (same row
     layout as ``sh_corpus``) and everything else as in the fp32 twin."""
 
-    def local(corpus, qvecs, scales, ids, qs, mask, qvalid):
+    def local(corpus, qvecs, scales, half, l1, l2, ids, qs, mask, qvalid):
         from ..kernels.quant import fused_scan_topk_batch_q
-        lids, lsims, lvalid = fused_scan_topk_batch_q(
-            corpus, qvecs, scales, qs, k, mask, metric,
+        lids, lsims, lvalid, _ = fused_scan_topk_batch_q(
+            corpus, qvecs, scales, half, l1, l2, qs, k, mask, metric,
             rescore_factor=rescore_factor, interpret=interpret,
             qvalid=qvalid)
         gids = jnp.where(lvalid, ids[jnp.maximum(lids, 0)], -1)
         keys = jnp.where(lvalid, order_key(metric, lsims), jnp.inf)
         return _merge_topk(metric, keys, gids, k, axes)
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(axes, None), P(axes, None), P(axes, None), P(axes),
+                  P(axes), P(axes), P(axes),
                   P(None, None), _mask_spec(axes, per_query_mask), P(None)),
         out_specs=(P(None, None), P(None, None), P(None, None)),
-        check_rep=False)
+        check_vma=False)
 
 
 def distributed_range_batch(mesh: Mesh, metric: Metric, capacity: int,
@@ -310,12 +309,12 @@ def distributed_range_batch(mesh: Mesh, metric: Metric, capacity: int,
             count = jax.lax.psum(count, ax)
         return out_ids, sims, valid, count
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(axes, None), P(axes), P(None, None), P(None),
                   _mask_spec(axes, per_query_mask), P(None)),
         out_specs=(P(None, None), P(None, None), P(None, None), P(None)),
-        check_rep=False)
+        check_vma=False)
 
 
 def distributed_range_batch_q(mesh: Mesh, metric: Metric, capacity: int,
@@ -348,10 +347,10 @@ def distributed_range_batch_q(mesh: Mesh, metric: Metric, capacity: int,
             count = jax.lax.psum(count, ax)
         return out_ids, sims, valid, count
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(axes, None), P(axes, None), P(axes, None), P(axes),
                   P(axes), P(axes), P(axes), P(None, None), P(None),
                   _mask_spec(axes, per_query_mask), P(None)),
         out_specs=(P(None, None), P(None, None), P(None, None), P(None)),
-        check_rep=False)
+        check_vma=False)

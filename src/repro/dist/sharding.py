@@ -190,17 +190,21 @@ class ShardedCorpus:
     @classmethod
     def build(cls, mesh: Mesh, corpus, axes: Sequence[str] = ("data",)
               ) -> "ShardedCorpus":
-        """Row-shard ``corpus`` over ``axes``, zero-padding to divisibility."""
+        """Row-shard ``corpus`` over ``axes``, zero-padding to divisibility.
+
+        Padding happens in host memory and each device receives only its
+        own rows: the whole corpus is never materialized on one device
+        (a corpus sized for the mesh need not fit on its first chip)."""
         axes = tuple(axes)
         shards = math.prod(mesh.shape[a] for a in axes)
         n = int(corpus.shape[0])
         pad = (-n) % shards
-        arr = jnp.asarray(corpus, jnp.float32)
-        ids = jnp.arange(n, dtype=jnp.int32)
+        arr = np.asarray(corpus, np.float32)
+        ids = np.arange(n, dtype=np.int32)
         if pad:
-            arr = jnp.concatenate(
-                [arr, jnp.zeros((pad, arr.shape[1]), arr.dtype)])
-            ids = jnp.concatenate([ids, jnp.full((pad,), -1, jnp.int32)])
+            arr = np.concatenate(
+                [arr, np.zeros((pad, arr.shape[1]), arr.dtype)])
+            ids = np.concatenate([ids, np.full((pad,), -1, np.int32)])
         return cls(
             mesh, axes,
             jax.device_put(arr, NamedSharding(mesh, PartitionSpec(axes, None))),

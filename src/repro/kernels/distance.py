@@ -20,7 +20,8 @@ from ..core.schema import Metric
 def _pairwise_kernel(q_ref, c_ref, out_ref, *, metric: Metric):
     qb = q_ref[...].astype(jnp.float32)         # (BQ, D)
     cb = c_ref[...].astype(jnp.float32)         # (BC, D)
-    ip = jnp.dot(qb, cb.T, preferred_element_type=jnp.float32)  # (BQ, BC)
+    ip = jnp.dot(qb, cb.T, precision=jax.lax.Precision.HIGHEST,
+                 preferred_element_type=jnp.float32)            # (BQ, BC)
     if metric == Metric.INNER_PRODUCT:
         out_ref[...] = -ip
     elif metric == Metric.L2:

@@ -9,8 +9,8 @@ import jax.numpy as jnp
 
 from ..core.schema import Metric
 from .distance import pairwise_keys_pallas
-from .range_scan import range_scan_batch_pallas, range_scan_pallas
-from .scan_topk import scan_topk_batch_pallas, scan_topk_pallas
+from .range_scan import range_scan_batch_pallas
+from .scan_topk import scan_topk_batch_pallas
 
 LANE = 128
 
@@ -57,6 +57,17 @@ def _block_sizes(n: int, qn: int, block_q: int, block_n: int):
     return bq, bn
 
 
+def best_first(keys: jnp.ndarray, ids: jnp.ndarray, k: int):
+    """Row-wise ``k`` smallest ``keys`` and their ``ids``, in (key, id)
+    order.  ``lax.top_k`` picks the set; the sort puts exact key ties in
+    ascending id order on every backend — XLA's TPU top_k does not keep a
+    wide row's ties in index order (seen at N = 1M), and the flat lanes
+    must agree bit for bit.  Returns (keys (…, k), ids (…, k))."""
+    neg, idx = jax.lax.top_k(-keys, k)
+    sel = jnp.take_along_axis(ids, idx, axis=-1)
+    return jax.lax.sort((-neg, sel), dimension=keys.ndim - 1, num_keys=2)
+
+
 def _qvalid_row_i8(qvalid: jnp.ndarray | None, qn: int,
                    block_q: int) -> jnp.ndarray:
     """Normalize a per-query valid vector (None | (Q,) bool) to the padded
@@ -75,55 +86,28 @@ def _qvalid_row_i8(qvalid: jnp.ndarray | None, qn: int,
 def fused_scan_topk(corpus: jnp.ndarray, query: jnp.ndarray, k: int,
                     row_mask: jnp.ndarray | None, metric: Metric,
                     block_n: int = 1024, interpret: bool | None = None):
-    """Drop-in fused replacement for FlatIndex.topk.
+    """Drop-in fused replacement for FlatIndex.topk: the batched kernel at
+    Q=1 (a shared (N,) mask stays a (N, 1) lane — no (Q, N) broadcast).
 
-    Returns (ids (k,), sims raw-metric (k,), valid (k,)).  Zero-padding on D
-    is metric-safe (contributes 0 to IP, 0 to L2 on both operands); padding on
-    N is masked out."""
-    interpret = _resolve_interpret(interpret)
-    n, d = corpus.shape
-    _, block_n = _block_sizes(n, 1, 1, block_n)
-    mask = jnp.ones((n,), jnp.bool_) if row_mask is None else row_mask
-    cp = _pad_dim(_pad_dim(corpus.astype(jnp.float32), LANE, 1), block_n, 0)
-    qp = _pad_dim(query.astype(jnp.float32).reshape(-1), LANE, 0)
-    mp = _pad_dim(mask.astype(jnp.int8).reshape(-1, 1), block_n, 0, value=0)
-    keys, ids = scan_topk_pallas(cp, qp, mp, k, metric, block_n=block_n,
-                                 interpret=interpret)
-    # stage 2: merge the (num_blocks, k) candidates
-    flat_keys = keys.reshape(-1)
-    flat_ids = ids.reshape(-1)
-    neg, idx = jax.lax.top_k(-flat_keys, k)
-    out_keys = -neg
-    valid = jnp.isfinite(out_keys)
-    out_ids = jnp.where(valid, flat_ids[idx], -1)
-    sims = jnp.where(valid,
-                     -out_keys if metric.is_similarity() else out_keys, 0.0)
-    return out_ids, sims, valid
+    Returns (ids (k,), sims raw-metric (k,), valid (k,))."""
+    ids, sims, valid = fused_scan_topk_batch(
+        corpus, query.reshape(1, -1), k, row_mask, metric, block_n=block_n,
+        interpret=interpret)
+    return ids[0], sims[0], valid[0]
 
 
 @functools.partial(jax.jit, static_argnames=("metric", "block_n", "interpret"))
 def fused_range_scan(corpus: jnp.ndarray, query: jnp.ndarray, radius,
                      row_mask: jnp.ndarray | None, metric: Metric,
                      block_n: int = 1024, interpret: bool | None = None):
-    """Drop-in fused replacement for FlatIndex.range_mask.
+    """Drop-in fused replacement for FlatIndex.range_mask: the batched
+    range kernel at Q=1.
 
     Returns (hit (N,), raw sims (N,), count)."""
-    from ..core.expr import order_key
-    interpret = _resolve_interpret(interpret)
-    n, d = corpus.shape
-    _, block_n = _block_sizes(n, 1, 1, block_n)
-    mask = jnp.ones((n,), jnp.bool_) if row_mask is None else row_mask
-    cp = _pad_dim(_pad_dim(corpus.astype(jnp.float32), LANE, 1), block_n, 0)
-    qp = _pad_dim(query.astype(jnp.float32).reshape(-1), LANE, 0)
-    mp = _pad_dim(mask.astype(jnp.int8).reshape(-1, 1), block_n, 0, value=0)
-    radius_key = order_key(metric, jnp.asarray(radius, jnp.float32))
-    keys, hits, counts = range_scan_pallas(cp, qp, radius_key, mp, metric,
-                                           block_n=block_n,
-                                           interpret=interpret)
-    keys = keys[:n, 0]
-    hit = hits[:n, 0] != 0
-    raw = jnp.where(hit, -keys if metric.is_similarity() else keys, 0.0)
-    return hit, raw, jnp.sum(counts)
+    hit, raw, counts = fused_range_scan_batch(
+        corpus, query.reshape(1, -1), radius, row_mask, metric,
+        block_n=block_n, interpret=interpret)
+    return hit[0], raw[0], counts[0]
 
 
 @functools.partial(jax.jit, static_argnames=("metric", "block_q", "block_c",
@@ -176,10 +160,9 @@ def fused_scan_topk_batch(corpus: jnp.ndarray, queries: jnp.ndarray, k: int,
     ids = ids.T
     base = (jnp.arange(num_n * k, dtype=jnp.int32) // k) * bn   # (num_n*k,)
     gids = jnp.where(ids >= 0, ids + base[None, :], -1)
-    neg, idx = jax.lax.top_k(-keys, k)                          # row-wise
-    out_keys = -neg
+    out_keys, out_ids = best_first(keys, gids, k)               # row-wise
     valid = jnp.isfinite(out_keys)
-    out_ids = jnp.where(valid, jnp.take_along_axis(gids, idx, axis=1), -1)
+    out_ids = jnp.where(valid, out_ids, -1)
     sims = jnp.where(valid,
                      -out_keys if metric.is_similarity() else out_keys, 0.0)
     return out_ids[:qn], sims[:qn], valid[:qn]
@@ -243,8 +226,9 @@ def fused_range_topk_batch(corpus: jnp.ndarray, queries: jnp.ndarray, radius,
         corpus, queries, radius, row_mask, metric, block_q=block_q,
         block_n=block_n, interpret=interpret, qvalid=qvalid)
     keys = jnp.where(hit, order_key(metric, raw), jnp.inf)
-    neg, sel = jax.lax.top_k(-keys, capacity)                # row-wise
-    valid = jnp.isfinite(-neg)
-    ids = jnp.where(valid, sel.astype(jnp.int32), -1)
+    rows = jax.lax.broadcasted_iota(jnp.int32, keys.shape, 1)
+    keys, sel = best_first(keys, rows, capacity)             # row-wise
+    valid = jnp.isfinite(keys)
+    ids = jnp.where(valid, sel, -1)
     sims = jnp.where(valid, jnp.take_along_axis(raw, sel, axis=1), 0.0)
     return ids, sims, valid, counts

@@ -16,59 +16,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from ..core.schema import Metric
-from .scan_topk import _keys_from_block, _keys_from_block_batch
+from .scan_topk import _keys_from_block_batch
 
 INF = float("inf")
-
-
-def _range_kernel(q_ref, r_ref, c_ref, m_ref, keys_out, hits_out, cnt_out, *,
-                  metric: Metric):
-    block = c_ref[...].astype(jnp.float32)          # (B, D)
-    q = q_ref[...].astype(jnp.float32)              # (1, D)
-    radius_key = r_ref[0, 0]
-    keys = _keys_from_block(block, q, metric)       # (B, 1)
-    mask = m_ref[...] != 0                          # (B, 1)
-    hit = mask & (keys <= radius_key)
-    keys_out[...] = jnp.where(hit, keys, INF)
-    hits_out[...] = hit.astype(jnp.int8)
-    cnt_out[...] = jnp.sum(hit.astype(jnp.int32), axis=0, keepdims=True)
-
-
-@functools.partial(jax.jit, static_argnames=("metric", "block_n", "interpret"))
-def range_scan_pallas(corpus: jnp.ndarray, query: jnp.ndarray,
-                      radius_key: jnp.ndarray, mask_i8: jnp.ndarray,
-                      metric: Metric, block_n: int = 1024,
-                      interpret: bool = True):
-    """Fused range scan. Returns ((Npad,1) masked keys, (Npad,1) int8 hits,
-    (num_blocks,1) per-block hit counts)."""
-    n, d = corpus.shape
-    assert n % block_n == 0
-    num_blocks = n // block_n
-    q2 = query.reshape(1, d)
-    r2 = jnp.asarray(radius_key, jnp.float32).reshape(1, 1)
-    kernel = functools.partial(_range_kernel, metric=metric)
-    keys, hits, counts = pl.pallas_call(
-        kernel,
-        grid=(num_blocks,),
-        in_specs=[
-            pl.BlockSpec((1, d), lambda i: (0, 0)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-            pl.BlockSpec((block_n, d), lambda i: (i, 0)),
-            pl.BlockSpec((block_n, 1), lambda i: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block_n, 1), lambda i: (i, 0)),
-            pl.BlockSpec((block_n, 1), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n, 1), jnp.float32),
-            jax.ShapeDtypeStruct((n, 1), jnp.int8),
-            jax.ShapeDtypeStruct((num_blocks, 1), jnp.int32),
-        ],
-        interpret=interpret,
-    )(q2, r2, corpus, mask_i8)
-    return keys, hits, counts
 
 
 def _range_batch_kernel(q_ref, r_ref, qv_ref, c_ref, m_ref, keys_out,
@@ -103,7 +53,9 @@ def range_scan_batch_pallas(corpus: jnp.ndarray, queries: jnp.ndarray,
     radius_keys (1, Qpad) order keys, mask (Npad, Qm) int8, Qm ∈ {1, Qpad},
     qvalid (1, Qpad) int8 — the per-query valid lane for size-bucket padding.
     Returns ((Npad, Qpad) masked keys, (Npad, Qpad) int8 hits,
-    (num_n_blocks, Qpad) per-block per-query hit counts)."""
+    (num_n_blocks, Qpad) per-block per-query hit counts).  Counts are
+    written as (num_n_blocks, 1, Qpad), n-block axis squeezed, for the same
+    tiling rule as the top-k kernels."""
     n, d = corpus.shape
     qn = queries.shape[0]
     assert n % block_n == 0 and qn % block_q == 0
@@ -128,13 +80,13 @@ def range_scan_batch_pallas(corpus: jnp.ndarray, queries: jnp.ndarray,
         out_specs=[
             pl.BlockSpec((block_n, block_q), lambda i, j: (j, i)),
             pl.BlockSpec((block_n, block_q), lambda i, j: (j, i)),
-            pl.BlockSpec((1, block_q), lambda i, j: (j, i)),
+            pl.BlockSpec((pl.squeezed, 1, block_q), lambda i, j: (j, 0, i)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((n, qn), jnp.float32),
             jax.ShapeDtypeStruct((n, qn), jnp.int8),
-            jax.ShapeDtypeStruct((num_n, qn), jnp.int32),
+            jax.ShapeDtypeStruct((num_n, 1, qn), jnp.int32),
         ],
         interpret=interpret,
     )(queries, radius_keys, qvalid_i8, corpus, mask_i8)
-    return keys, hits, counts
+    return keys, hits, counts.reshape(num_n, qn)

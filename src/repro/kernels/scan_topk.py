@@ -9,12 +9,15 @@ recomputes a distance.
 TPU shape discipline:
 * corpus tiles (BLOCK_N, D) stream HBM→VMEM via BlockSpec; D padded to a
   lane multiple (128) by the wrapper.
-* the query lives in VMEM as (1, D); scores come from a (BLOCK_N, D)·(D, 1)
-  MXU matmul with fp32 accumulation (preferred_element_type).
-* per-block top-k runs as a k-step extract-min loop on (BLOCK_N, 1) column
-  vectors — small-k selection is VPU-friendly; no unsupported `top_k` inside
-  Mosaic.  A second-stage `lax.top_k` over (num_blocks × k) candidates runs
-  outside the kernel (standard two-stage TPU top-k).
+* a (BLOCK_Q, D) query tile stays in VMEM; keys come from one
+  (BLOCK_N, D)·(D, BLOCK_Q) MXU matmul per grid cell with fp32 contraction.
+  A single query is a one-query batch (BLOCK_Q = 8 after padding), so there
+  is one kernel per query class and no (BLOCK_N, 1) column layout, which
+  Mosaic cannot broadcast across lanes.
+* per-cell top-k runs as a k-step column-parallel extract-min — small-k
+  selection is VPU-friendly; no unsupported `top_k` inside Mosaic.  A second
+  `lax.top_k` over (num_blocks × k) candidates runs outside the kernel
+  (standard two-stage TPU top-k).
 """
 from __future__ import annotations
 
@@ -27,51 +30,10 @@ from jax.experimental import pallas as pl
 from ..core.schema import Metric
 
 INF = float("inf")  # python literal: safe inside kernel bodies (no captured consts)
-
-
-def _extract_topk(keys_col: jnp.ndarray, ids_col: jnp.ndarray, k: int):
-    """(B,1) masked keys + ids -> (1,k) smallest keys and their ids.
-
-    k-step extract-min with where-based dynamic updates (Mosaic-safe: no
-    gathers, no dynamic-slice on vectors)."""
-    b = keys_col.shape[0]
-    iota_col = jax.lax.broadcasted_iota(jnp.int32, (b, 1), 0)
-    iota_row = jax.lax.broadcasted_iota(jnp.int32, (1, k), 1)
-
-    def body(j, carry):
-        vals, out_keys, out_ids = carry
-        m = jnp.min(vals)
-        # first index attaining the min (ties broken low)
-        idxv = jnp.min(jnp.where(vals == m, iota_col, b))
-        sel = iota_col == idxv
-        picked_id = jnp.max(jnp.where(sel, ids_col, -2147483648))
-        keep = jnp.isfinite(m)
-        out_keys = jnp.where(iota_row == j, jnp.where(keep, m, INF), out_keys)
-        out_ids = jnp.where(iota_row == j,
-                            jnp.where(keep, picked_id, -1), out_ids)
-        vals = jnp.where(sel, INF, vals)
-        return vals, out_keys, out_ids
-
-    init = (keys_col, jnp.full((1, k), INF), jnp.full((1, k), -1, jnp.int32))
-    _, out_keys, out_ids = jax.lax.fori_loop(0, k, body, init)
-    return out_keys, out_ids
-
-
-def _keys_from_block(block: jnp.ndarray, q: jnp.ndarray,
-                     metric: Metric) -> jnp.ndarray:
-    """(B,D),(1,D) -> (B,1) order keys. MXU matmul + metric epilogue."""
-    ip = jnp.dot(block, q.T, preferred_element_type=jnp.float32)  # (B,1)
-    if metric == Metric.INNER_PRODUCT:
-        return -ip
-    if metric == Metric.L2:
-        b2 = jnp.sum(block * block, axis=1, keepdims=True)
-        q2 = jnp.sum(q * q, axis=1, keepdims=True)  # (1,1)
-        return b2 - 2.0 * ip + q2
-    if metric == Metric.COSINE:
-        bn = jnp.sqrt(jnp.sum(block * block, axis=1, keepdims=True))
-        qn = jnp.sqrt(jnp.sum(q * q, axis=1, keepdims=True))
-        return -(ip / (bn * qn + 1e-12))
-    raise ValueError(metric)
+# fp32 contraction on the MXU (Mosaic's `contract_precision<fp32>`): the
+# default would round both operands to bf16, and the engine's flat scans
+# are exact.  A no-op for the CPU interpreter, which always contracts fp32.
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def _sq_rowvec(x: jnp.ndarray) -> jnp.ndarray:
@@ -79,6 +41,7 @@ def _sq_rowvec(x: jnp.ndarray) -> jnp.ndarray:
     (no vector transpose/relayout inside Mosaic)."""
     ones = jnp.ones((1, x.shape[1]), jnp.float32)
     return jax.lax.dot_general(ones, x * x, (((1,), (1,)), ((), ())),
+                               precision=HIGHEST,
                                preferred_element_type=jnp.float32)
 
 
@@ -87,22 +50,19 @@ def _keys_from_block_batch(block: jnp.ndarray, qs: jnp.ndarray,
     """(B,D),(BQ,D) -> (B,BQ) order keys. One MXU matmul per corpus tile
     amortized over the whole query tile — the batched-execution hot loop.
 
-    ``block`` may arrive in bf16 (the quantized kernels stream the bf16
-    twin MXU-native — DESIGN.md §13): the contraction accumulates in fp32
-    via ``preferred_element_type``, and the norm epilogues widen first.
-    bf16 -> fp32 conversion is exact, so both are bitwise identical to a
-    pre-widened block (and a no-op for fp32 callers)."""
+    Both operands arrive in fp32 (the quantized kernels widen their tile
+    first — Mosaic has no mixed-dtype contraction)."""
     ip = jax.lax.dot_general(block, qs, (((1,), (1,)), ((), ())),
+                             precision=HIGHEST,
                              preferred_element_type=jnp.float32)  # (B, BQ)
     if metric == Metric.INNER_PRODUCT:
         return -ip
-    blk = block.astype(jnp.float32)
     if metric == Metric.L2:
-        b2 = jnp.sum(blk * blk, axis=1, keepdims=True)       # (B, 1)
+        b2 = jnp.sum(block * block, axis=1, keepdims=True)   # (B, 1)
         q2 = _sq_rowvec(qs)                                  # (1, BQ)
         return b2 - 2.0 * ip + q2
     if metric == Metric.COSINE:
-        bn = jnp.sqrt(jnp.sum(blk * blk, axis=1, keepdims=True))
+        bn = jnp.sqrt(jnp.sum(block * block, axis=1, keepdims=True))
         qn = jnp.sqrt(_sq_rowvec(qs))
         return -(ip / (bn * qn + 1e-12))
     raise ValueError(metric)
@@ -173,8 +133,11 @@ def scan_topk_batch_pallas(corpus: jnp.ndarray, queries: jnp.ndarray,
     Inputs are pre-padded by ops.py: corpus (Npad, Dpad), queries (Qpad, Dpad),
     mask (Npad, Qm) int8 with Qm ∈ {1, Qpad} (shared vs per-query masks), and
     qvalid (1, Qpad) int8 — the per-query valid lane for size-bucket padding.
-    Returns (num_n_blocks*k, Qpad) keys and LOCAL ids (kernel-native layout;
-    ops.py rebases ids by n-block and transposes to query-major)."""
+    Returns (num_n_blocks*k, Qpad) keys and LOCAL ids (ops.py rebases ids by
+    n-block and transposes to query-major).  The kernel writes them as
+    (num_n_blocks, k, Qpad) with the n-block axis squeezed out of each block,
+    so a block's last two dims equal the array's — Mosaic's tiling rule for a
+    k that is not a multiple of 8 — and the reshape back is free."""
     n, d = corpus.shape
     qn = queries.shape[0]
     assert n % block_n == 0 and qn % block_q == 0, (n, block_n, qn, block_q)
@@ -196,64 +159,52 @@ def scan_topk_batch_pallas(corpus: jnp.ndarray, queries: jnp.ndarray,
             mspec,                                             # mask tile
         ],
         out_specs=[
-            pl.BlockSpec((k, block_q), lambda i, j: (j, i)),
-            pl.BlockSpec((k, block_q), lambda i, j: (j, i)),
+            pl.BlockSpec((pl.squeezed, k, block_q), lambda i, j: (j, 0, i)),
+            pl.BlockSpec((pl.squeezed, k, block_q), lambda i, j: (j, 0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((num_n * k, qn), jnp.float32),
-            jax.ShapeDtypeStruct((num_n * k, qn), jnp.int32),
+            jax.ShapeDtypeStruct((num_n, k, qn), jnp.float32),
+            jax.ShapeDtypeStruct((num_n, k, qn), jnp.int32),
         ],
         interpret=interpret,
     )(queries, qvalid_i8, corpus, mask_i8)
-    return keys, ids
+    return keys.reshape(num_n * k, qn), ids.reshape(num_n * k, qn)
 
 
-def _scan_topk_kernel(q_ref, c_ref, m_ref, keys_out, ids_out, *,
-                      k: int, block_n: int, metric: Metric):
-    i = pl.program_id(0)
-    block = c_ref[...].astype(jnp.float32)          # (B, D)
-    q = q_ref[...].astype(jnp.float32)              # (1, D)
-    keys = _keys_from_block(block, q, metric)       # (B, 1)
-    mask = m_ref[...]                               # (B, 1) int8 validity
-    keys = jnp.where(mask != 0, keys, INF)
-    base = (i * block_n).astype(jnp.int32)
-    ids_col = base + jax.lax.broadcasted_iota(jnp.int32, (block_n, 1), 0)
-    out_keys, out_ids = _extract_topk(keys, ids_col, k)
-    keys_out[...] = out_keys
-    ids_out[...] = out_ids
+def _keys_batch_kernel(q_ref, c_ref, keys_out, *, metric: Metric):
+    """Grid (num_q_blocks, num_n_blocks): the unmasked (BLOCK_N, BLOCK_Q)
+    order keys of one tile — the contraction the top-k and range kernels
+    run, so a replay through it is bitwise their keys."""
+    keys_out[...] = _keys_from_block_batch(c_ref[...].astype(jnp.float32),
+                                           q_ref[...].astype(jnp.float32),
+                                           metric)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("k", "metric", "block_n", "interpret"))
-def scan_topk_pallas(corpus: jnp.ndarray, query: jnp.ndarray,
-                     mask_i8: jnp.ndarray, k: int, metric: Metric,
-                     block_n: int = 1024, interpret: bool = True):
-    """Stage 1 (Pallas): per-block fused top-k candidates.
+@functools.partial(jax.jit, static_argnames=("metric", "block_q", "block_n",
+                                             "interpret"))
+def keys_batch_pallas(corpus: jnp.ndarray, queries: jnp.ndarray,
+                      metric: Metric, block_q: int = 128, block_n: int = 1024,
+                      interpret: bool = True):
+    """(Npad, Qpad) fp32 order keys of every (row, query) pair.
 
-    Inputs are pre-padded by ops.py: corpus (Npad, Dpad), mask (Npad, 1) int8.
-    Returns (num_blocks, k) keys and ids."""
+    Inputs pre-padded: corpus (Npad, Dpad), queries (Qpad, Dpad).  The
+    quantized lanes' fp32 rescore replays candidate rows through this
+    kernel at the fp32 kernels' own (block_n, block_q) tile shape, which is
+    what makes the rescored keys bitwise the fp32 lane's on the chip as well
+    as in the interpreter (an XLA dot outside the kernel is lowered
+    differently)."""
     n, d = corpus.shape
-    assert n % block_n == 0, (n, block_n)
-    num_blocks = n // block_n
-    q2 = query.reshape(1, d)
-    kernel = functools.partial(_scan_topk_kernel, k=k, block_n=block_n,
-                               metric=metric)
-    keys, ids = pl.pallas_call(
+    qn = queries.shape[0]
+    assert n % block_n == 0 and qn % block_q == 0, (n, block_n, qn, block_q)
+    kernel = functools.partial(_keys_batch_kernel, metric=metric)
+    return pl.pallas_call(
         kernel,
-        grid=(num_blocks,),
+        grid=(qn // block_q, n // block_n),
         in_specs=[
-            pl.BlockSpec((1, d), lambda i: (0, 0)),          # query
-            pl.BlockSpec((block_n, d), lambda i: (i, 0)),    # corpus tile
-            pl.BlockSpec((block_n, 1), lambda i: (i, 0)),    # mask tile
+            pl.BlockSpec((block_q, d), lambda i, j: (i, 0)),
+            pl.BlockSpec((block_n, d), lambda i, j: (j, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((1, k), lambda i: (i, 0)),
-            pl.BlockSpec((1, k), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((num_blocks, k), jnp.float32),
-            jax.ShapeDtypeStruct((num_blocks, k), jnp.int32),
-        ],
+        out_specs=pl.BlockSpec((block_n, block_q), lambda i, j: (j, i)),
+        out_shape=jax.ShapeDtypeStruct((n, qn), jnp.float32),
         interpret=interpret,
-    )(q2, corpus, mask_i8)
-    return keys, ids
+    )(queries, corpus)

@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..compile_cache import enable_compile_cache
 from ..serving.resilience import (AdmissionConfig, AdmissionController,
                                   DegradePolicy, validate_binds)
 from ..serving.scheduler import ResilientScheduler, SchedulerConfig
@@ -318,6 +319,7 @@ def main(argv=None) -> int:
     ap.add_argument("--deadline-ms", type=float, default=200.0)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.front_door:
         return asyncio.run(_front_door_demo(args))

@@ -27,7 +27,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from ..dist.sharding import constrain, current_mesh, current_rules
 from .config import ModelConfig
@@ -239,8 +238,8 @@ def _moe_shard_map(p, cfg: ModelConfig, x, capacity_factor: float):
     if e.num_shared_experts:
         in_specs += [mlp_spec, mlp_spec, mlp_spec_o]
         args += [p["shared_wi"], p["shared_wg"], p["shared_wo"]]
-    fn = shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
-                   out_specs=(x_spec, P()), check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
+                       out_specs=(x_spec, P()), check_vma=False)
     return fn(*args)
 
 

@@ -134,7 +134,6 @@ def build_compressed_dp_step(cfg: ModelConfig, opt_cfg: AdamWConfig,
 
     State gains an ``err`` pytree (the feedback memory)."""
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     def local_step(params, opt, err, batch):
         loss, grads = jax.value_and_grad(_loss_fn)(params, cfg, batch)
@@ -149,9 +148,9 @@ def build_compressed_dp_step(cfg: ModelConfig, opt_cfg: AdamWConfig,
         if cfg.input_mode == "tokens" else \
         {"embeds": P(dp_axis), "labels": P(dp_axis)}
     rep = P()
-    fn = shard_map(
+    fn = jax.shard_map(
         local_step, mesh=mesh,
         in_specs=(rep, rep, rep, batch_spec),
         out_specs=(rep, rep, rep, rep),
-        check_rep=False)
+        check_vma=False)
     return jax.jit(fn)

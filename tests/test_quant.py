@@ -277,6 +277,70 @@ def test_adversarial_ties_and_duplicates(mode):
     assert ids[8:] == [279, 278, 277, 276], ids
 
 
+@pytest.mark.parametrize("mode", MODES)
+def test_dense_neighbourhood_falls_back_to_fp32(mode):
+    """A tight cluster whose spread is below the quantization step: the
+    quantized ranking inside it is noise, so the smallest candidate set
+    (rescore_factor=1) misses true top-k rows, the certificate fails, and
+    the batch must still equal the fp32 path bit for bit."""
+    n, k = 1024, 16
+    cat = make_laion_catalog(n_rows=n, n_queries=4, dim=DIM, n_modes=8,
+                             num_categories=4, seed=0)
+    rng = np.random.default_rng(11)
+    u = rng.standard_normal(DIM).astype(np.float32)
+    u /= np.linalg.norm(u)
+    vecs = 0.1 * rng.standard_normal((n, DIM)).astype(np.float32)
+    vecs[::2] = u + 2e-3 * rng.standard_normal((n // 2, DIM))
+    tab = cat.table("laion")
+    cols = {name: tab[name] for name in tab.schema.names()}
+    cols["vec"] = cols["embedding"] = jnp.asarray(vecs)
+    cat.register("products", Table(tab.schema, cols))
+    ksql = ("SELECT sample_id FROM products WHERE price < ${p} "
+            "ORDER BY DISTANCE(embedding, ${qv}) LIMIT %d" % k)
+    qs = u + 1e-2 * rng.standard_normal((4, DIM)).astype(np.float32)
+    binds = [{"qv": q, "p": np.float32(1e9)} for q in qs]
+    want = connect(cat, EngineOptions(engine="brute", use_pallas=True)
+                   ).prepare(ksql).execute(binds)
+    stmt = connect(cat, EngineOptions(engine="brute", use_pallas=True,
+                                      quant=mode, rescore_factor=1)
+                   ).prepare(ksql)
+    got = stmt.execute(binds)
+    _trees_equal(want.data, got.data, ctx=f"dense/{mode}")
+    assert stmt.executor.quant_topk == {"batches": 1, "fp32_fallbacks": 1}
+
+
+@pytest.mark.parametrize("mode", MODES)
+def test_separated_neighbourhood_certifies(mode):
+    """The opposite of the dense case: each query's top-k rows stand far
+    above the rest of the corpus, so the certificate holds, the batch
+    never runs the fp32 kernel, and the result still equals the fp32 path
+    bit for bit."""
+    n, k = 1024, 4
+    cat = make_laion_catalog(n_rows=n, n_queries=4, dim=DIM, n_modes=8,
+                             num_categories=4, seed=0)
+    rng = np.random.default_rng(12)
+    qs = rng.standard_normal((4, DIM)).astype(np.float32)
+    qs /= np.linalg.norm(qs, axis=1, keepdims=True)
+    vecs = 0.05 * rng.standard_normal((n, DIM)).astype(np.float32)
+    for i, q in enumerate(qs):
+        rows = slice(64 * i, 64 * i + k)
+        vecs[rows] = q + 1e-2 * rng.standard_normal((k, DIM))
+    tab = cat.table("laion")
+    cols = {name: tab[name] for name in tab.schema.names()}
+    cols["vec"] = cols["embedding"] = jnp.asarray(vecs)
+    cat.register("products", Table(tab.schema, cols))
+    ksql = ("SELECT sample_id FROM products "
+            "ORDER BY DISTANCE(embedding, ${qv}) LIMIT %d" % k)
+    binds = [{"qv": q} for q in qs]
+    want = connect(cat, EngineOptions(engine="brute", use_pallas=True)
+                   ).prepare(ksql).execute(binds)
+    stmt = connect(cat, EngineOptions(engine="brute", use_pallas=True,
+                                      quant=mode)).prepare(ksql)
+    got = stmt.execute(binds)
+    _trees_equal(want.data, got.data, ctx=f"separated/{mode}")
+    assert stmt.executor.quant_topk == {"batches": 1, "fp32_fallbacks": 0}
+
+
 # ---------------------------------------------------------------------------
 # composition: sharded shards=1, live-delta, re-registered twins
 # ---------------------------------------------------------------------------
