@@ -47,13 +47,11 @@ The last line of stdout, printed only when every check passed, is
 from __future__ import annotations
 
 import argparse
-import contextlib
 import dataclasses
 import json
 import os
 import shutil
 import sys
-import time
 
 ROOT = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, os.path.join(ROOT, "src"))
@@ -104,37 +102,6 @@ LANES = {
     "pallas": dict(engine="brute", use_pallas=True),
     "int8": dict(engine="brute", use_pallas=True, quant="int8"),
 }
-
-
-# ---------------------------------------------------------------------------
-# timing: wall clock per phase, with JAX's own trace/lower/compile durations
-# ---------------------------------------------------------------------------
-
-class CompileClock:
-    """Sums JAX's trace, lowering and backend-compile durations."""
-    EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
-              "/jax/core/compile/jaxpr_to_mlir_module_duration",
-              "/jax/core/compile/backend_compile_duration")
-
-    def __init__(self):
-        self.seconds = 0.0
-        jax.monitoring.register_event_duration_secs_listener(self._on_event)
-
-    def _on_event(self, event: str, duration: float, **_):
-        if event in self.EVENTS:
-            self.seconds += duration
-
-
-@contextlib.contextmanager
-def phase(name: str, clock: CompileClock):
-    """Print a phase's wall time and the compile time inside it.  Work in
-    the phase ends on the host (checks read the outputs), so the wall time
-    includes the device."""
-    c0, t0 = clock.seconds, time.perf_counter()
-    yield
-    wall = time.perf_counter() - t0
-    print(f"phase {name}: wall_s={wall} compile_s={clock.seconds - c0}",
-          flush=True)
 
 
 # ---------------------------------------------------------------------------
@@ -376,7 +343,7 @@ def report(checks: Checks, name: str, oks: list, recs: list, lane: str):
 # one chip
 # ---------------------------------------------------------------------------
 
-def run_one_chip(w: World, clock: CompileClock, checks: Checks):
+def run_one_chip(w: World, checks: Checks):
     opts = {lane: EngineOptions(probe=w.cfg.probe, **kw)
             for lane, kw in LANES.items()}
     dbs = {lane: connect(w.catalog, o) for lane, o in opts.items()}
@@ -390,24 +357,20 @@ def run_one_chip(w: World, clock: CompileClock, checks: Checks):
         for b in (1, B64):
             for sel in SELECTIVITIES:
                 name = f"q1_sel{sel}/{lane}/b{b}"
-                with phase(name, clock):
-                    out = execute(q1, q1_binds(w, sel), b)
-                    report(checks, name, *q1_check(w, out, sel, lane), lane)
+                out = execute(q1, q1_binds(w, sel), b)
+                report(checks, name, *q1_check(w, out, sel, lane), lane)
                 outs[("q1", sel, b, lane)] = out
             name = f"q2/{lane}/b{b}"
-            with phase(name, clock):
-                out = execute(q2, q2_binds(w), b)
-                report(checks, name, *q2_check(w, out, lane), lane)
+            out = execute(q2, q2_binds(w), b)
+            report(checks, name, *q2_check(w, out, lane), lane)
             outs[("q2", b, lane)] = out
             name = f"q3/{lane}/b{b}"
-            with phase(name, clock):
-                out = host(q3[b].execute([{"r": w.radius}]))
-                report(checks, name, *q3_check(w, out, b, lane), lane)
+            out = host(q3[b].execute([{"r": w.radius}]))
+            report(checks, name, *q3_check(w, out, b, lane), lane)
             outs[("q3", b, lane)] = out
             name = f"q5/{lane}/b{b}"
-            with phase(name, clock):
-                out = execute(q5, q5_binds(w), b)
-                report(checks, name, *q5_check(w, out, lane), lane)
+            out = execute(q5, q5_binds(w), b)
+            report(checks, name, *q5_check(w, out, lane), lane)
             outs[("q5", b, lane)] = out
         if lane == "int8":
             # Q1 executions whose top-k certificate failed (fp32 rerun)
@@ -419,50 +382,47 @@ def run_one_chip(w: World, clock: CompileClock, checks: Checks):
                             f"{key[:-1]}")
 
     pallas = dbs["pallas"].prepare(Q1)
-    with phase("q1/pallas/single_dict", clock):
-        single = host(pallas.execute(q1_binds(w, 1.0)[0]))
-        bucket = {k: v[0] for k, v in outs[("q1", 1.0, 1, "pallas")].items()
-                  if k != "stats"}
-        same = same_bits(single, bucket)
-        print(f"single_dict_vs_bucket1 q1: bit_identical={same}", flush=True)
-        checks.expect(same, "q1 single-dict path differs from bucket 1")
+    single = host(pallas.execute(q1_binds(w, 1.0)[0]))
+    bucket = {k: v[0] for k, v in outs[("q1", 1.0, 1, "pallas")].items()
+              if k != "stats"}
+    same = same_bits(single, bucket)
+    print(f"single_dict_vs_bucket1 q1: bit_identical={same}", flush=True)
+    checks.expect(same, "q1 single-dict path differs from bucket 1")
 
-    with phase("serve/pallas/q1", clock):
-        server = dbs["pallas"].serve(pallas, max_batch=B64)
-        binds = q1_binds(w, 0.03)[:5]
-        rids = [server.submit(**bi) for bi in binds]
-        server.flush()
-        answered = 0
-        for i, rid in enumerate(rids):
-            res = server.result(rid)
-            answered += int(np.array_equal(
-                np.asarray(res["ids"]),
-                outs[("q1", 0.03, B64, "pallas")]["ids"][i]))
-        print(f"serve answered={answered}/{len(rids)}", flush=True)
-        checks.expect(answered == len(rids),
-                      "served requests differ from the batch results")
+    server = dbs["pallas"].serve(pallas, max_batch=B64)
+    binds = q1_binds(w, 0.03)[:5]
+    rids = [server.submit(**bi) for bi in binds]
+    server.flush()
+    answered = 0
+    for i, rid in enumerate(rids):
+        res = server.result(rid)
+        answered += int(np.array_equal(
+            np.asarray(res["ids"]),
+            outs[("q1", 0.03, B64, "pallas")]["ids"][i]))
+    print(f"serve answered={answered}/{len(rids)}", flush=True)
+    checks.expect(answered == len(rids),
+                  "served requests differ from the batch results")
 
-    with phase("aot/pallas/q1_b64", clock):
-        shutil.rmtree(AOT_DIR, ignore_errors=True)
-        binds = q1_binds(w, 0.03)
-        cold_db = connect(w.catalog, opts["pallas"], aot_cache_path=AOT_DIR)
-        cold = cold_db.prepare(Q1)
-        cold_out = host(cold.execute(binds))
-        warm_db = connect(w.catalog, opts["pallas"], aot_cache_path=AOT_DIR)
-        warm = warm_db.prepare(Q1)
-        warm_out = host(warm.execute(binds))
-        traces = sum(warm.executor.trace_counts.values())
-        errors = (cold_db.cache_info().aot["errors"]
-                  + warm_db.cache_info().aot["errors"])
-        same = (same_bits(warm_out, cold_out)
-                and same_bits(warm_out, outs[("q1", 0.03, B64, "pallas")]))
-        print(f"aot cold_traces={sum(cold.executor.trace_counts.values())} "
-              f"warm_traces={traces} errors={errors} "
-              f"loaded={dict(warm.executor.aot_loaded)} "
-              f"bit_identical={same}", flush=True)
-        checks.expect(traces == 0, f"AOT warm prepare traced {traces} times")
-        checks.expect(errors == 0, f"AOT cache reported {errors} errors")
-        checks.expect(same, "AOT warm results differ")
+    shutil.rmtree(AOT_DIR, ignore_errors=True)
+    binds = q1_binds(w, 0.03)
+    cold_db = connect(w.catalog, opts["pallas"], aot_cache_path=AOT_DIR)
+    cold = cold_db.prepare(Q1)
+    cold_out = host(cold.execute(binds))
+    warm_db = connect(w.catalog, opts["pallas"], aot_cache_path=AOT_DIR)
+    warm = warm_db.prepare(Q1)
+    warm_out = host(warm.execute(binds))
+    traces = sum(warm.executor.trace_counts.values())
+    errors = (cold_db.cache_info().aot["errors"]
+              + warm_db.cache_info().aot["errors"])
+    same = (same_bits(warm_out, cold_out)
+            and same_bits(warm_out, outs[("q1", 0.03, B64, "pallas")]))
+    print(f"aot cold_traces={sum(cold.executor.trace_counts.values())} "
+          f"warm_traces={traces} errors={errors} "
+          f"loaded={dict(warm.executor.aot_loaded)} "
+          f"bit_identical={same}", flush=True)
+    checks.expect(traces == 0, f"AOT warm prepare traced {traces} times")
+    checks.expect(errors == 0, f"AOT cache reported {errors} errors")
+    checks.expect(same, "AOT warm results differ")
     return pallas
 
 
@@ -470,7 +430,7 @@ def run_one_chip(w: World, clock: CompileClock, checks: Checks):
 # four chips
 # ---------------------------------------------------------------------------
 
-def run_four_chips(w: World, clock: CompileClock, checks: Checks):
+def run_four_chips(w: World, checks: Checks):
     checks.expect(len(jax.devices()) >= 4,
                   f"--chips 4 needs 4 devices, have {len(jax.devices())}")
     lane = dict(probe=w.cfg.probe, **LANES["pallas"])
@@ -483,8 +443,7 @@ def run_four_chips(w: World, clock: CompileClock, checks: Checks):
     for plan, o in plans.items():
         db = connect(w.catalog, o)
         for name, sql, binds in cases:
-            with phase(f"{name}/{plan}/b{B64}", clock):
-                outs[(name, plan)] = host(db.prepare(sql).execute(binds))
+            outs[(name, plan)] = host(db.prepare(sql).execute(binds))
     for name, _, _ in cases:
         ref = outs[(name, "single")]
         for plan in ("dist1", "dist4"):
@@ -551,19 +510,17 @@ def main(argv=None) -> int:
               "CPU with --rows 20000)", file=sys.stderr)
         return 2
     enable_compile_cache()
-    clock = CompileClock()
     checks = Checks()
-    with phase("build_catalog_reference", clock):
-        w = build_world(args.rows or PAPER_ROWS, args.seed,
-                        ivf=args.chips == 1)
+    w = build_world(args.rows or PAPER_ROWS, args.seed,
+                    ivf=args.chips == 1)
     print(f"rows={w.corpus.shape[0]} dim={w.corpus.shape[1]} "
           f"queries={w.qvecs.shape[0]} "
           f"nlist={w.cfg.nlist if args.chips == 1 else None} "
           f"radius={w.radius} seed={args.seed}", flush=True)
     if args.chips == 4:
-        pallas_stmt = run_four_chips(w, clock, checks)
+        pallas_stmt = run_four_chips(w, checks)
     else:
-        pallas_stmt = run_one_chip(w, clock, checks)
+        pallas_stmt = run_one_chip(w, checks)
     info = device_check(checks, pallas_stmt, w)
     if checks.failures:
         print(f"{len(checks.failures)} check(s) failed:", file=sys.stderr)

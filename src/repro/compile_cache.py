@@ -21,7 +21,14 @@ def enable_compile_cache() -> str:
 
     Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and
     nothing here overrides it.  Otherwise the cache goes to
-    ``<checkout>/.jax_cache``."""
+    ``<checkout>/.jax_cache``.
+
+    Entries are keyed on the programs' metadata too: a profile names each
+    device op by the ``jax.named_scope`` path in its executable's
+    metadata, and with that left out of the key an executable cached
+    before a scope was added or moved would bring stale names into every
+    trace."""
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if placed:
         return placed

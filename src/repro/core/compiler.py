@@ -26,6 +26,8 @@ reference and by callers with a fixed batch size).
 """
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import dataclasses
 import hashlib
 from typing import Any, Callable
@@ -207,6 +209,35 @@ def _pad_leading(v, bucket: int) -> np.ndarray:
         [v, np.broadcast_to(v[-1:], (pad,) + v.shape[1:])])
 
 
+# -- profiler spans of the serving path --------------------------------------
+# ``chase.drain`` (opened by the serving scheduler) encloses one drain; its
+# children ``chase.stack`` / ``chase.pad`` / ``chase.dispatch`` /
+# ``chase.fetch`` / ``chase.slice`` carry the same ``drain`` id.  A
+# ``TraceAnnotation`` records nothing unless a profiler session is open.
+
+_DRAIN_ID: contextvars.ContextVar[int] = contextvars.ContextVar(
+    "chase_drain", default=-1)
+
+
+def span(name: str) -> jax.profiler.TraceAnnotation:
+    """A host span of the serving path, tagged with the drain it runs in
+    (-1 outside a drain)."""
+    return jax.profiler.TraceAnnotation(name, drain=_DRAIN_ID.get())
+
+
+@contextlib.contextmanager
+def drain_span(drain: int, size: int, bucket: int):
+    """The ``chase.drain`` span: ``size`` requests run in ``bucket``; the
+    spans opened inside it carry ``drain`` as their id."""
+    token = _DRAIN_ID.set(drain)
+    try:
+        with jax.profiler.TraceAnnotation("chase.drain", drain=drain,
+                                          size=size, bucket=bucket):
+            yield
+    finally:
+        _DRAIN_ID.reset(token)
+
+
 FALLBACK_STAT = "fp32_fallback"
 
 
@@ -299,18 +330,20 @@ class BucketedExecutor:
         leading bucket axis, so tests (and debuggers) can observe that pad
         rows are inert — empty results, zero probe/distance counters."""
         bucket = _bucket_for(qn)
-        padded = {k: _pad_leading(v, bucket) for k, v in binds.items()}
-        valid = np.arange(bucket) < qn
-        if probe_budget is not None:
-            budget = np.asarray(probe_budget, np.int32)
-            if budget.ndim >= 1 and budget.shape[0] == qn:
-                budget = _pad_leading(budget, bucket)
-            probe_budget = budget
+        with span("chase.pad"):
+            padded = {k: _pad_leading(v, bucket) for k, v in binds.items()}
+            valid = np.arange(bucket) < qn
+            if probe_budget is not None:
+                budget = np.asarray(probe_budget, np.int32)
+                if budget.ndim >= 1 and budget.shape[0] == qn:
+                    budget = _pad_leading(budget, bucket)
+                probe_budget = budget
         args = (self.arrays, padded, valid, probe_budget)
-        if self._aot is not None:
-            out = self._aot_call(bucket, args)
-        else:
-            out = self.executable(bucket)(*args)
+        with span("chase.dispatch"):
+            if self._aot is not None:
+                out = self._aot_call(bucket, args)
+            else:
+                out = self.executable(bucket)(*args)
         return self.note_fallback(out), bucket, valid
 
     # -- persistent AOT plan cache (DESIGN.md §15) --------------------------
@@ -376,7 +409,8 @@ class BucketedExecutor:
         one tiny executable per distinct Q — see :func:`_pad_leading`."""
         qn = _stacked_qn(binds)
         out, _bucket, _valid = self.run_padded(binds, qn, probe_budget)
-        return jax.tree.map(lambda v: np.asarray(v)[:qn], out)
+        with span("chase.fetch"):     # the host waits for the device here
+            return jax.tree.map(lambda v: np.asarray(v)[:qn], out)
 
 
 def _stacked_qn(binds: dict) -> int:
@@ -541,8 +575,9 @@ class CompiledQuery:
             # host-side stack: a jnp.stack over N request arrays compiles a
             # fresh concatenate per DISTINCT N — per-batch-size compile
             # latency the bucketed serving path exists to kill
-            return {k: np.stack([np.asarray(b[k]) for b in binds_list])
-                    for k in keys}
+            with span("chase.stack"):
+                return {k: np.stack([np.asarray(b[k]) for b in binds_list])
+                        for k in keys}
         binds = {k: jnp.asarray(v) for k, v in stacked.items()}
         qe = self.analysis.query_expr
         if isinstance(qe, Param) and qe.name in binds:

@@ -23,6 +23,7 @@ full sort), which we also lower faithfully (``brute_sort``).
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 from typing import Any, Callable
@@ -1594,6 +1595,11 @@ def build_vknn_sf_batch(a: Analysis, catalog: Catalog, opts: EngineOptions,
     dist = (_dist_topk_core(opts, metric, k,
                             per_query_mask=mask_fn is not None or live)
             if opts.dist is not None else None)
+    # the fused flat lane (brute engine, or no index) names its mask step
+    flat_lane = (dist is None and opts.use_pallas and not (
+        opts.engine in ("chase", "vbase", "pase") and index is not None))
+    mask_scope = (functools.partial(jax.named_scope, "chase.flat.mask")
+                  if flat_lane else contextlib.nullcontext)
 
     def fn(arrays, binds, qvalid=None, probe_budget=None):
         corpus = arrays["corpus"]
@@ -1601,11 +1607,12 @@ def build_vknn_sf_batch(a: Analysis, catalog: Catalog, opts: EngineOptions,
         qs = jnp.asarray(binds[qparam.name])                     # (Q, D)
         qn = qs.shape[0]
         dmask = None
-        if live:
-            row_mask, dmask = _live_scan_masks(a.structured_predicate,
-                                               arrays, binds, qn)
-        else:
-            row_mask = jax.vmap(mask_fn)(binds) if mask_fn else None  # (Q, N)
+        with mask_scope():
+            if live:
+                row_mask, dmask = _live_scan_masks(a.structured_predicate,
+                                                   arrays, binds, qn)
+            else:                                               # (Q, N)
+                row_mask = jax.vmap(mask_fn)(binds) if mask_fn else None
         if dist is not None:
             ids, sims, valid, stats = dist(arrays, qs,
                                            _as_per_query(row_mask, qn),

@@ -468,6 +468,12 @@ def _round_schedule(index: IVFIndex, cfg: ProbeConfig):
     return B, n_rounds, max_probes
 
 
+def round_width(index: IVFIndex, cfg: ProbeConfig) -> int:
+    """Clusters each batched probe round gathers per active query."""
+    return _round_schedule(index, cfg)[0]
+
+
+@jax.named_scope("chase.ivf.order")
 def _order_pad_batch(index: IVFIndex, qs: jnp.ndarray, B: int, n_rounds: int,
                      max_probes: int):
     """Per-query probe order padded to n_rounds*B with -1 sentinels."""
@@ -479,6 +485,7 @@ def _order_pad_batch(index: IVFIndex, qs: jnp.ndarray, B: int, n_rounds: int,
     return order, bounds
 
 
+@jax.named_scope("chase.ivf.gather")
 def _scan_clusters_batch(index: IVFIndex, corpus: jnp.ndarray,
                          qs: jnp.ndarray, clusters: jnp.ndarray,
                          row_mask: jnp.ndarray | None):
@@ -533,6 +540,7 @@ def ivf_topk_batch(index: IVFIndex, corpus: jnp.ndarray, qs: jnp.ndarray,
         r, *_rest, active = state
         return (r < n_rounds) & jnp.any(active)
 
+    @jax.named_scope("chase.ivf.probe_round")
     def body(state):
         r, bk, bi, no_imp, probes, evals, active = state
         cl = jax.lax.dynamic_slice_in_dim(order, r * B, B, axis=1)
@@ -540,11 +548,12 @@ def ivf_topk_batch(index: IVFIndex, corpus: jnp.ndarray, qs: jnp.ndarray,
             index, corpus, qs, cl, row_mask)
         valid = valid & rm_hit
         old_kth = bk[:, k - 1]
-        merged_k, merged_i = jax.vmap(
-            lambda a, b, c, d, e: _merge_topk(a, b, c, d, e, k))(
-                bk, bi, keys, ids, valid)
-        bk2 = jnp.where(active[:, None], merged_k, bk)
-        bi2 = jnp.where(active[:, None], merged_i, bi)
+        with jax.named_scope("chase.ivf.merge"):
+            merged_k, merged_i = jax.vmap(
+                lambda a, b, c, d, e: _merge_topk(a, b, c, d, e, k))(
+                    bk, bi, keys, ids, valid)
+            bk2 = jnp.where(active[:, None], merged_k, bk)
+            bi2 = jnp.where(active[:, None], merged_i, bi)
         improved = (bk2[:, k - 1] < old_kth) | (~jnp.isfinite(old_kth)
                                                 & jnp.isfinite(bk2[:, k - 1]))
         n_probed = jnp.minimum(B, max_probes - r * B)
@@ -607,6 +616,7 @@ def ivf_range_batch(index: IVFIndex, corpus: jnp.ndarray, qs: jnp.ndarray,
         r, *_rest, active = state
         return (r < n_rounds) & jnp.any(active)
 
+    @jax.named_scope("chase.ivf.probe_round")
     def body(state):
         (r, out_ids, out_keys, count, has_in, out_cnt, probes, evals,
          active) = state
@@ -702,6 +712,7 @@ def ivf_range_category_batch(index: IVFIndex, corpus: jnp.ndarray,
         r, *_rest, active = state
         return (r < n_rounds) & jnp.any(active)
 
+    @jax.named_scope("chase.ivf.probe_round")
     def body(state):
         (r, out_ids, out_keys, count, has_in, out_cnt, seen, counts, kth,
          no_new, probes, evals, active) = state

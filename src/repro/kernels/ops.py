@@ -148,23 +148,28 @@ def fused_scan_topk_batch(corpus: jnp.ndarray, queries: jnp.ndarray, k: int,
     n, d = corpus.shape
     qn = queries.shape[0]
     bq, bn = _block_sizes(n, qn, block_q, block_n)
-    cp = _pad_dim(_pad_dim(corpus.astype(jnp.float32), LANE, 1), bn, 0)
+    with jax.named_scope("chase.flat.pad_corpus"):
+        cp = _pad_dim(_pad_dim(corpus.astype(jnp.float32), LANE, 1), bn, 0)
     qp = _pad_dim(_pad_dim(queries.astype(jnp.float32), LANE, 1), bq, 0)
     mp = _mask_nq_i8(row_mask, n, qn, bn, bq)
     qv = _qvalid_row_i8(qvalid, qn, bq)
-    keys, ids = scan_topk_batch_pallas(cp, qp, mp, qv, k, metric, block_q=bq,
-                                       block_n=bn, interpret=interpret)
+    with jax.named_scope("chase.flat.scan"):
+        keys, ids = scan_topk_batch_pallas(cp, qp, mp, qv, k, metric,
+                                           block_q=bq, block_n=bn,
+                                           interpret=interpret)
     # stage 2: query-major layout, rebase local ids by n-block, merge per row
-    num_n = cp.shape[0] // bn
-    keys = keys.T                                               # (Qpad, nb*k)
-    ids = ids.T
-    base = (jnp.arange(num_n * k, dtype=jnp.int32) // k) * bn   # (num_n*k,)
-    gids = jnp.where(ids >= 0, ids + base[None, :], -1)
-    out_keys, out_ids = best_first(keys, gids, k)               # row-wise
-    valid = jnp.isfinite(out_keys)
-    out_ids = jnp.where(valid, out_ids, -1)
-    sims = jnp.where(valid,
-                     -out_keys if metric.is_similarity() else out_keys, 0.0)
+    with jax.named_scope("chase.flat.merge"):
+        num_n = cp.shape[0] // bn
+        keys = keys.T                                           # (Qpad, nb*k)
+        ids = ids.T
+        base = (jnp.arange(num_n * k, dtype=jnp.int32) // k) * bn
+        gids = jnp.where(ids >= 0, ids + base[None, :], -1)
+        out_keys, out_ids = best_first(keys, gids, k)           # row-wise
+        valid = jnp.isfinite(out_keys)
+        out_ids = jnp.where(valid, out_ids, -1)
+        sims = jnp.where(valid,
+                         -out_keys if metric.is_similarity() else out_keys,
+                         0.0)
     return out_ids[:qn], sims[:qn], valid[:qn]
 
 

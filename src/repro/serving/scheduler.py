@@ -44,6 +44,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import itertools
 import time
 from typing import Any, Callable
 
@@ -51,7 +52,13 @@ import jax
 
 import numpy as np
 
+from ..core.compiler import drain_span, span
+from ..index.ivf import round_width
 from .resilience import DeadlineExceededError, LoadController
+
+# process-wide drain ids, so that the ``chase.drain`` spans of several
+# schedulers in one trace stay distinct
+_DRAIN_SEQ = itertools.count()
 
 
 @dataclasses.dataclass(frozen=True)
@@ -240,8 +247,14 @@ class BatchScheduler:
         self._queue: collections.deque[_Request] = collections.deque()
         self._results: dict[int, Any] = {}
         self._next_rid = 0
+        # ``wait_s``: summed queue wait (take - submit, on ``clock``) of the
+        # executed requests; ``probe_rounds`` / ``rows_gathered`` /
+        # ``rows_scored``: lock-step IVF rounds, rows those rounds gathered
+        # (pad and frozen queries included) and rows actually scored
         self.counters = {"submitted": 0, "executed": 0, "batches": 0,
-                         "shed_deadline": 0, "failed": 0}
+                         "shed_deadline": 0, "failed": 0, "wait_s": 0.0,
+                         "probe_rounds": 0, "rows_gathered": 0,
+                         "rows_scored": 0}
 
     # -- online API ---------------------------------------------------------
 
@@ -365,21 +378,48 @@ class BatchScheduler:
         if not self._queue:
             return done
         entries = self._take()
-        try:
-            out = self.execute([r.binds for r in entries])
-        except Exception as e:
-            # fault containment: the failure is scoped to this batch —
-            # every member completes with the error, the queue keeps
-            # draining, and nothing is left dangling (no hangs).
-            for r in entries:
-                self._results[r.rid] = e
-            self.counters["failed"] += len(entries)
-        else:
-            for i, r in enumerate(entries):
-                self._results[r.rid] = self._slice(out, i)
-            self.counters["executed"] += len(entries)
-            self.counters["batches"] += 1
+        taken = self.clock()
+        bucket = self.compiled.executor.bucket_for(len(entries))
+        with drain_span(next(_DRAIN_SEQ), len(entries), bucket):
+            try:
+                out = self.execute([r.binds for r in entries])
+            except Exception as e:
+                # fault containment: the failure is scoped to this batch —
+                # every member completes with the error, the queue keeps
+                # draining, and nothing is left dangling (no hangs).
+                for r in entries:
+                    self._results[r.rid] = e
+                self.counters["failed"] += len(entries)
+            else:
+                with span("chase.slice"):
+                    for i, r in enumerate(entries):
+                        self._results[r.rid] = self._slice(out, i)
+                self._count(entries, taken, out, bucket)
         return done + [r.rid for r in entries]
+
+    def _count(self, entries: list[_Request], taken: float, out,
+               bucket: int) -> None:
+        """Counters of one executed drain.  The IVF probes advance
+        lock-step by one round width per round until the batch's slowest
+        query stops, so the rounds are its probes over the width."""
+        c = self.counters
+        c["executed"] += len(entries)
+        c["batches"] += 1
+        c["wait_s"] += sum(taken - r.arrival for r in entries)
+        data = getattr(out, "data", out)
+        stats = data.get("stats") if isinstance(data, dict) else None
+        index = self.compiled.executor.arrays.get("index")
+        if not stats or "probes" not in stats or index is None:
+            return
+        probes = np.asarray(stats["probes"])
+        width = round_width(index, self.compiled.executor.plan.options.probe)
+        rounds = -(-int(probes.max(initial=0)) // width)
+        if rounds == 0:
+            return                      # the plan probed no IVF index
+        lanes = bucket * (probes.size // max(probes.shape[0], 1))
+        c["probe_rounds"] += rounds
+        c["rows_gathered"] += rounds * lanes * width * index.cap
+        c["rows_scored"] += int(np.asarray(stats["distance_evals"]).sum())
 
     def _slice(self, out, i: int):
         """Extract request ``i``'s view of a batch output (overridable —
