@@ -6,6 +6,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
 
 from ..core.schema import Metric
 from .distance import pairwise_keys_pallas
@@ -40,7 +41,9 @@ def _pad_dim(x: jnp.ndarray, mult: int, axis: int, value=0.0) -> jnp.ndarray:
 def _mask_nq_i8(row_mask: jnp.ndarray | None, n: int, qn: int,
                 block_n: int, block_q: int) -> jnp.ndarray:
     """Normalize a mask (None | (N,) shared | (Q, N) per-query) to the padded
-    (Npad, Qm) int8 layout the batched kernels consume (Qm ∈ {1, Qpad})."""
+    (Npad, Qm) int8 layout the batched kernels consume (Qm ∈ {1, Qpad}).
+    Its zero rows past N are what keep the overhang of a corpus read in
+    place (a last block past N) out of every result."""
     if row_mask is None:
         m = jnp.ones((n, 1), jnp.int8)
     elif row_mask.ndim == 1:
@@ -148,8 +151,10 @@ def fused_scan_topk_batch(corpus: jnp.ndarray, queries: jnp.ndarray, k: int,
     n, d = corpus.shape
     qn = queries.shape[0]
     bq, bn = _block_sizes(n, qn, block_q, block_n)
+    # the corpus is read in place along N (the kernel's last block may
+    # overhang it); only a lane pad of D, a no-op at D = 512, copies it
     with jax.named_scope("chase.flat.pad_corpus"):
-        cp = _pad_dim(_pad_dim(corpus.astype(jnp.float32), LANE, 1), bn, 0)
+        cp = _pad_dim(corpus.astype(jnp.float32), LANE, 1)
     qp = _pad_dim(_pad_dim(queries.astype(jnp.float32), LANE, 1), bq, 0)
     mp = _mask_nq_i8(row_mask, n, qn, bn, bq)
     qv = _qvalid_row_i8(qvalid, qn, bq)
@@ -159,7 +164,7 @@ def fused_scan_topk_batch(corpus: jnp.ndarray, queries: jnp.ndarray, k: int,
                                            interpret=interpret)
     # stage 2: query-major layout, rebase local ids by n-block, merge per row
     with jax.named_scope("chase.flat.merge"):
-        num_n = cp.shape[0] // bn
+        num_n = pl.cdiv(n, bn)
         keys = keys.T                                           # (Qpad, nb*k)
         ids = ids.T
         base = (jnp.arange(num_n * k, dtype=jnp.int32) // k) * bn
@@ -190,7 +195,7 @@ def fused_range_scan_batch(corpus: jnp.ndarray, queries: jnp.ndarray, radius,
     n, d = corpus.shape
     qn = queries.shape[0]
     bq, bn = _block_sizes(n, qn, block_q, block_n)
-    cp = _pad_dim(_pad_dim(corpus.astype(jnp.float32), LANE, 1), bn, 0)
+    cp = _pad_dim(corpus.astype(jnp.float32), LANE, 1)   # N read in place
     qp = _pad_dim(_pad_dim(queries.astype(jnp.float32), LANE, 1), bq, 0)
     mp = _mask_nq_i8(row_mask, n, qn, bn, bq)
     qv = _qvalid_row_i8(qvalid, qn, bq)

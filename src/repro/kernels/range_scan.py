@@ -6,6 +6,10 @@ per-block hit count plus masked keys.  The (data-dependent) compaction happens
 outside the kernel; what the kernel saves is the materialization of raw
 scores + a second filtering pass — the paper's fusion argument applied to
 Algorithm 1's inner loop.
+
+The corpus is read in place along N, as in ``scan_topk.py``: the grid's last
+block may overhang it, its rows past N hold undefined values, and the int8
+mask's zero rows past N keep them out of every hit, key and count.
 """
 from __future__ import annotations
 
@@ -49,19 +53,24 @@ def range_scan_batch_pallas(corpus: jnp.ndarray, queries: jnp.ndarray,
                             block_n: int = 1024, interpret: bool = True):
     """Query-tiled fused range scan.
 
-    Inputs pre-padded: corpus (Npad, Dpad), queries (Qpad, Dpad),
-    radius_keys (1, Qpad) order keys, mask (Npad, Qm) int8, Qm ∈ {1, Qpad},
-    qvalid (1, Qpad) int8 — the per-query valid lane for size-bucket padding.
+    The corpus (N, Dpad) is read in place over ``cdiv(N, block_n)`` blocks,
+    the last of which may overhang N.  The small operands arrive padded:
+    queries (Qpad, Dpad), radius_keys (1, Qpad) order keys, mask (Npad, Qm)
+    int8 with Npad = cdiv(N, block_n)·block_n, Qm ∈ {1, Qpad}, zero past N
+    (so the overhang registers no hit), and qvalid (1, Qpad) int8 — the
+    per-query valid lane for size-bucket padding.
     Returns ((Npad, Qpad) masked keys, (Npad, Qpad) int8 hits,
     (num_n_blocks, Qpad) per-block per-query hit counts).  Counts are
     written as (num_n_blocks, 1, Qpad), n-block axis squeezed, for the same
     tiling rule as the top-k kernels."""
     n, d = corpus.shape
     qn = queries.shape[0]
-    assert n % block_n == 0 and qn % block_q == 0
-    assert qvalid_i8.shape == (1, qn), (qvalid_i8.shape, qn)
-    num_n = n // block_n
+    num_n = pl.cdiv(n, block_n)
     num_q = qn // block_q
+    npad = num_n * block_n
+    assert mask_i8.shape[0] == npad, (mask_i8.shape, n, block_n)
+    assert qn % block_q == 0, (qn, block_q)
+    assert qvalid_i8.shape == (1, qn), (qvalid_i8.shape, qn)
     per_query_mask = mask_i8.shape[1] != 1
     mspec = (pl.BlockSpec((block_n, block_q), lambda i, j: (j, i))
              if per_query_mask
@@ -83,8 +92,8 @@ def range_scan_batch_pallas(corpus: jnp.ndarray, queries: jnp.ndarray,
             pl.BlockSpec((pl.squeezed, 1, block_q), lambda i, j: (j, 0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((n, qn), jnp.float32),
-            jax.ShapeDtypeStruct((n, qn), jnp.int8),
+            jax.ShapeDtypeStruct((npad, qn), jnp.float32),
+            jax.ShapeDtypeStruct((npad, qn), jnp.int8),
             jax.ShapeDtypeStruct((num_n, 1, qn), jnp.int32),
         ],
         interpret=interpret,

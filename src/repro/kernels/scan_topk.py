@@ -7,8 +7,13 @@ structured-filter mask in-register, and maintains top-k candidates — the full
 recomputes a distance.
 
 TPU shape discipline:
-* corpus tiles (BLOCK_N, D) stream HBM→VMEM via BlockSpec; D padded to a
-  lane multiple (128) by the wrapper.
+* corpus tiles (BLOCK_N, D) stream HBM→VMEM via BlockSpec straight from the
+  caller's corpus: N is read in place, never copied to a BLOCK_N multiple,
+  so the grid's last block may overhang the corpus.  Those rows' values are
+  undefined (zeros or garbage on the chip, NaN in interpret mode); the
+  int8 mask, padded with zeros to whole blocks, makes their keys INF
+  whatever they hold, so they reach no result.  D is padded to a lane
+  multiple (128) by the wrapper.
 * a (BLOCK_Q, D) query tile stays in VMEM; keys come from one
   (BLOCK_N, D)·(D, BLOCK_Q) MXU matmul per grid cell with fp32 contraction.
   A single query is a one-query batch (BLOCK_Q = 8 after padding), so there
@@ -130,9 +135,13 @@ def scan_topk_batch_pallas(corpus: jnp.ndarray, queries: jnp.ndarray,
                            interpret: bool = True):
     """Stage 1 (Pallas), query-tiled: per (q-block, n-block) top-k candidates.
 
-    Inputs are pre-padded by ops.py: corpus (Npad, Dpad), queries (Qpad, Dpad),
-    mask (Npad, Qm) int8 with Qm ∈ {1, Qpad} (shared vs per-query masks), and
-    qvalid (1, Qpad) int8 — the per-query valid lane for size-bucket padding.
+    The corpus (N, Dpad) is read in place: the grid runs over
+    ``cdiv(N, block_n)`` blocks, and the last may overhang N.  The small
+    operands arrive padded by ops.py: queries (Qpad, Dpad), mask (Npad, Qm)
+    int8 with Npad = cdiv(N, block_n)·block_n and Qm ∈ {1, Qpad} (shared vs
+    per-query masks), zero on the rows past N — which is what keeps the
+    overhang's undefined rows out of every result — and qvalid (1, Qpad)
+    int8, the per-query valid lane for size-bucket padding.
     Returns (num_n_blocks*k, Qpad) keys and LOCAL ids (ops.py rebases ids by
     n-block and transposes to query-major).  The kernel writes them as
     (num_n_blocks, k, Qpad) with the n-block axis squeezed out of each block,
@@ -140,10 +149,11 @@ def scan_topk_batch_pallas(corpus: jnp.ndarray, queries: jnp.ndarray,
     k that is not a multiple of 8 — and the reshape back is free."""
     n, d = corpus.shape
     qn = queries.shape[0]
-    assert n % block_n == 0 and qn % block_q == 0, (n, block_n, qn, block_q)
-    assert qvalid_i8.shape == (1, qn), (qvalid_i8.shape, qn)
-    num_n = n // block_n
+    num_n = pl.cdiv(n, block_n)
     num_q = qn // block_q
+    assert mask_i8.shape[0] == num_n * block_n, (mask_i8.shape, n, block_n)
+    assert qn % block_q == 0, (qn, block_q)
+    assert qvalid_i8.shape == (1, qn), (qvalid_i8.shape, qn)
     per_query_mask = mask_i8.shape[1] != 1
     mspec = (pl.BlockSpec((block_n, block_q), lambda i, j: (j, i))
              if per_query_mask

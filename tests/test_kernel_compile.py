@@ -122,3 +122,35 @@ def test_int8_range_compiles(shape):
              shape((N, D)), shape((N, D), jnp.int8), shape((N, 1)),
              shape((N,)), shape((N,)), shape((N,)), shape((64, D)),
              shape((64,)))
+
+
+@pytest.mark.parametrize("q,mask", [(1, None), (64, None), (1, "per_query"),
+                                    (64, "per_query"), (1, "range"),
+                                    (64, "range")])
+def test_flat_scan_reads_corpus_in_place(shape, q, mask):
+    """The flat kernels read the 2.048 GB corpus in place: no executable may
+    hold a corpus-sized temporary (a copy padded to a block multiple; with
+    it the top-k executables held 2.18 GB, the range ones 2.7-2.8 GB)."""
+    corpus_bytes = N * D * 4
+    bound = corpus_bytes // 2
+    if mask == "range":
+        compiled = _compile(
+            lambda c, qs, r: fused_range_scan_batch(c, qs, r, None, METRIC,
+                                                    interpret=False),
+            shape((N, D)), shape((q, D)), shape((q,)))
+        if q == 64:
+            # its own (N, 64) keys and hits, lane-padded to 128 columns and
+            # transposed to (64, N), take 1.15 GB: bounded by the corpus
+            bound = corpus_bytes
+    elif mask is None:
+        compiled = _compile(
+            lambda c, qs: fused_scan_topk_batch(c, qs, 50, None, METRIC,
+                                                interpret=False),
+            shape((N, D)), shape((q, D)))
+    else:
+        compiled = _compile(
+            lambda c, qs, m: fused_scan_topk_batch(c, qs, 50, m, METRIC,
+                                                   interpret=False),
+            shape((N, D)), shape((q, D)), shape((q, N), jnp.bool_))
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < bound, (temp, bound)
