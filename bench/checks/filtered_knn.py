@@ -22,6 +22,10 @@ Numbers compared (each with its limit from the configuration file):
   lies below the float64 K-th best (exact guarantee only);
 * ``recall``: the mean recall@K against the float64 top K (recall
   guarantee only; its limit is the configuration's stated floor).
+
+The mix's ``check`` names ``k``, the query vector's bind
+(``vector_bind``), the bound's (``bound_bind``) and the filtered column
+(``column``).
 """
 from __future__ import annotations
 
@@ -156,6 +160,29 @@ def control(corpus, column_host, column_dev, qv, bounds, k: int,
     served = {"ids": ids, "sim": vals, "valid": np.isfinite(vals)}
     return compare(corpus, column_host, column_dev, qv, bounds, served, k,
                    guarantee)
+
+
+def _inputs(dataset, binds: dict, spec: dict):
+    col = dataset.columns[spec["column"]]
+    return (dataset.corpus, col, jnp.asarray(col), binds[spec["vector_bind"]],
+            np.asarray(binds[spec["bound_bind"]], np.float32))
+
+
+def compare_run(dataset, binds: dict, served: dict, spec: dict,
+                guarantee: dict, limits: dict) -> dict:
+    """:func:`compare` on the harness's sample: ``binds`` and ``served``
+    hold each bind and answer key stacked over the sampled requests."""
+    corpus, col, col_dev, qv, bounds = _inputs(dataset, binds, spec)
+    return compare(corpus, col, col_dev, qv, bounds, served, spec["k"],
+                   guarantee)
+
+
+def control_run(dataset, binds: dict, spec: dict, guarantee: dict,
+                precision: str, limits: dict) -> dict:
+    """:func:`control` on the harness's sample."""
+    corpus, col, col_dev, qv, bounds = _inputs(dataset, binds, spec)
+    return control(corpus, col, col_dev, qv, bounds, spec["k"], guarantee,
+                   precision)
 
 
 def judge(numbers: dict, limits: dict) -> list:

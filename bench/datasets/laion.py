@@ -5,8 +5,8 @@ The same mixture model and schema as the program's ``data/laion.py``
 category columns, the Q1-Q6 table aliases), rewritten on ``jax.random`` so
 that the paper's 1,000,000 x 512 corpus is made in one jitted call on the
 chip instead of in host NumPy.  The benchmark keeps the device arrays it
-made (corpus, price) for its own reference; the program gets them only
-through its public ``Catalog``.
+made (corpus, scalar columns) for its own reference; the program gets them
+only through its public ``Catalog``.
 
 Configuration keys read here: ``rows``, ``dim``, ``modes``, ``groups``,
 ``group_spread``, ``spread``, ``query_spread``, ``price_lognormal``
@@ -33,11 +33,24 @@ from repro.core import (Catalog, Metric, Schema, Table, category_col,
                         float_col, int_col, vector_col)
 
 
+class HostColumns(dict):
+    """Scalar column name -> host NumPy array, each copied from the device
+    the first time it is read."""
+
+    def __init__(self, device: dict):
+        super().__init__()
+        self._device = device
+
+    def __missing__(self, name: str) -> np.ndarray:
+        self[name] = value = np.asarray(self._device[name])
+        return value
+
+
 @dataclasses.dataclass
 class Dataset:
     catalog: Catalog
     corpus: jax.Array          # (N, D) float32, on the device
-    columns: dict              # scalar column name -> host NumPy array
+    columns: HostColumns       # scalar column name -> host NumPy array
     modes: jax.Array           # (M, D) mixture centres (the queries' too)
     query_spread: float
 
@@ -120,5 +133,5 @@ def build(cfg: dict, key: jax.Array) -> Dataset:
     cat = Catalog()
     for alias in ("laion", "products", "images", "recipes", "movies"):
         cat.register(alias, laion)
-    return Dataset(cat, corpus, {"price": np.asarray(cols["price"])},
-                   centres, float(cfg["query_spread"]))
+    return Dataset(cat, corpus, HostColumns(cols), centres,
+                   float(cfg["query_spread"]))

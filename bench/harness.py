@@ -14,7 +14,9 @@ the mix's buckets (all of this is ``setup_s``), then drive ``submit`` /
 ``poll`` / ``result`` from one thread for the window, answer what is still
 queued, read the device's peak memory, free the program, and compare a
 seeded sample of the answers with the plain reference under
-``bench/checks/``.
+``bench/checks/``.  Nothing here names a bind, a column or an answer key
+but ``probes``: the mix file and its check module hold what a statement
+binds and answers.
 """
 from __future__ import annotations
 
@@ -230,7 +232,8 @@ class Timeline:
     start: np.ndarray       # start of the drain that answered it
     done: np.ndarray        # its sliced result on the host (nan: none)
     ok: np.ndarray
-    answers: dict           # per attempted request: ids, sim, valid, probes
+    answers: dict           # per attempted request: each array of the
+                            # answer but stats, and probes
     drains: list            # (start, end, requests) of drains in the window
     counters: tuple         # scheduler counters at the open and the close
     compiles: int           # compile events inside the window
@@ -241,18 +244,28 @@ def _annotate(name: str):
     return jax.profiler.TraceAnnotation(name)
 
 
+def _answer_slots(out: dict, n: int, answers: dict) -> list:
+    """An (n, ...) array in ``answers`` for each array-valued key of the
+    first answer but ``stats``, shaped and typed as that answer's value;
+    rows of requests that get no answer stay zero."""
+    slots = []
+    for key, value in out.items():
+        if key != "stats" and hasattr(value, "shape"):
+            answers[key] = np.zeros((n, *value.shape), value.dtype)
+            slots.append((key, answers[key]))
+    return slots
+
+
 def drive(world: World, cell: Cell, seconds: float,
           clock: CompileClock) -> Timeline:
     server, req = world.server, world.requests
     n = len(req.binds)
-    k = cell.traffic["check"]["k"]
     due = np.full(n, np.nan)
     sent, start, done = (np.full(n, np.nan) for _ in range(3))
     ok = np.zeros(n, bool)
-    ids = np.full((n, k), -1, np.int32)
-    sim = np.zeros((n, k), np.float32)
-    valid = np.zeros((n, k), bool)
     probes = np.zeros(n, np.int64)
+    answers = {"probes": probes}
+    slots: list = []            # (key, its (n, ...) array) of every answer
     rid_of: dict = {}           # queued request id -> request, oldest first
     drains: list = []
     max_wait_s = cell.traffic["server"]["max_wait_ms"] * 1e-3
@@ -274,8 +287,10 @@ def drive(world: World, cell: Cell, seconds: float,
                 except Exception as e:          # noqa: BLE001 -- counted
                     print(f"request {i} failed: {e!r}", file=sys.stderr)
                 else:
-                    ids[i], sim[i], valid[i] = (out["ids"], out["sim"],
-                                                out["valid"])
+                    if not slots:
+                        slots.extend(_answer_slots(out, n, answers))
+                    for key, column in slots:
+                        column[i] = out[key]
                     stats = out.get("stats", {})
                     probes[i] = int(stats.get("probes", 0))
                     ok[i] = True
@@ -349,8 +364,7 @@ def drive(world: World, cell: Cell, seconds: float,
     gc.unfreeze()
     m = nxt
     return Timeline(t0, end, due[:m], sent[:m], start[:m], done[:m], ok[:m],
-                    {"ids": ids[:m], "sim": sim[:m], "valid": valid[:m],
-                     "probes": probes[:m]},
+                    {key: column[:m] for key, column in answers.items()},
                     drains, (counters0, counters1), compiles)
 
 
@@ -373,21 +387,31 @@ def _sample(timeline: Timeline, n: int, seed: int) -> np.ndarray:
     return np.sort(rng.choice(answered, n, replace=False))
 
 
+def sampled_binds(requests, pick: np.ndarray) -> dict:
+    """Each bind name -> its values stacked over the sampled requests."""
+    return {name: np.stack([requests.binds[i][name] for i in pick])
+            for name in requests.binds[0]}
+
+
 def check(world: World, cell: Cell, timeline: Timeline, seed: int):
-    """The compared numbers of a seeded sample, and their judgement."""
-    import jax.numpy as jnp
+    """The compared numbers of a seeded sample, and their judgement.
+
+    The mix's ``check`` names a module under ``bench/checks/`` that holds
+    ``compare_run(dataset, binds, served, spec, guarantee, limits)`` (the
+    compared numbers of the sampled answers), ``control_run(dataset,
+    binds, spec, guarantee, precision, limits)`` (the same numbers of its
+    reference computed one precision below, for ``readings.py``) and
+    ``judge(numbers, limits)``.  ``binds`` and ``served`` map each bind
+    name and answer key to its values over the sample; ``spec`` is the
+    mix's ``check`` object; ``limits`` the configuration's."""
     spec = cell.traffic["check"]
     checker = load_module("checks", spec["module"], cell.root)
     pick = _sample(timeline, spec["sample"], seed)
-    req = world.requests
-    qv = np.stack([req.binds[i][spec["vector_bind"]] for i in pick])
-    bounds = np.asarray([req.binds[i][spec["bound_bind"]] for i in pick],
-                        np.float32)
-    col = world.dataset.columns[spec["column"]]
-    served = {k: timeline.answers[k][pick] for k in ("ids", "sim", "valid")}
-    numbers = checker.compare(world.dataset.corpus, col, jnp.asarray(col),
-                              qv, bounds, served, spec["k"],
-                              cell.config["guarantee"])
+    served = {k: v[pick] for k, v in timeline.answers.items()}
+    numbers = checker.compare_run(world.dataset,
+                                  sampled_binds(world.requests, pick),
+                                  served, spec, cell.config["guarantee"],
+                                  cell.config["limits"])
     numbers["failed"] = int((~timeline.ok).sum())
     limits = {"failed": {"max": 0}, **cell.config["limits"]}
     return numbers, checker.judge(numbers, limits)

@@ -26,8 +26,6 @@ import json
 import os
 import sys
 
-import numpy as np
-
 BENCH = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(BENCH)
 
@@ -47,7 +45,6 @@ def apply_sets(config: dict, sets: list) -> None:
 
 def readings(name: str, seconds: float, seeds: list, control_seeds: list,
              device_check=None, adjust=None, sets=()) -> dict:
-    import jax.numpy as jnp
     import harness
     cell = harness.load_cell(name)
     if adjust is not None:
@@ -70,18 +67,13 @@ def readings(name: str, seconds: float, seeds: list, control_seeds: list,
             numbers, _ = harness.check(world, cell, tl, seed)
             sides.append(("program", numbers))
         if seed in control_seeds:
-            pick = harness._sample(tl, spec["sample"], seed)
-            req = world.requests
-            qv = np.stack([req.binds[i][spec["vector_bind"]] for i in pick])
-            bounds = np.asarray([req.binds[i][spec["bound_bind"]]
-                                 for i in pick], np.float32)
-            col = world.dataset.columns[spec["column"]]
+            binds = harness.sampled_binds(
+                world.requests, harness._sample(tl, spec["sample"], seed))
             for side, precision in (("control", "bf16_3x"),
                                     ("control_high", "high")):
-                sides.append((side, checker.control(
-                    world.dataset.corpus, col, jnp.asarray(col), qv,
-                    bounds, spec["k"], cell.config["guarantee"],
-                    precision)))
+                sides.append((side, checker.control_run(
+                    world.dataset, binds, spec, cell.config["guarantee"],
+                    precision, cell.config["limits"])))
         probes = tl.answers["probes"][tl.ok]
         for side, numbers in sides:
             print(json.dumps({"seed": seed, "side": side, **numbers,

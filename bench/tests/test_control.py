@@ -17,9 +17,10 @@ def test_control_fails_where_the_program_passes(cell):
     out = readings_mod.readings(cell, 1.0, SEEDS, SEEDS,
                                 device_check=lambda c: jax.devices()[:c],
                                 adjust=shrink)
-    limits = {"failed": {"max": 0},
-              **harness.load_cell(cell).config["limits"]}
-    judge = harness.load_module("checks", "filtered_knn").judge
+    loaded = harness.load_cell(cell)
+    limits = {"failed": {"max": 0}, **loaded.config["limits"]}
+    judge = harness.load_module("checks",
+                                loaded.traffic["check"]["module"]).judge
     for numbers in out["program"]:
         assert all(ok for *_, ok in judge(numbers, limits)), numbers
     for numbers in out["control"]:

@@ -83,8 +83,6 @@ def test_trace_readers():
     r = _record()
     for name in ("idle_share.flat", "idle_share.ivf"):
         assert _read(name, r) == pytest.approx(75.0)
-    # host time per drain: (10 + 1 + 5) / 3 ms
-    assert _read("host_ms_per_batch.flat", r) == pytest.approx(16 / 3)
     n, d = 1_000_000, 512
     mem = (n * d * 4 + n * 4) / 819e9
     floors = [max(2 * n * d * q / 197e12, mem) for q in (64, 64, 64)]
@@ -94,8 +92,7 @@ def test_trace_readers():
 
 def test_trace_readers_find_nothing_without_a_trace():
     r = _record(trace_on=False)
-    for name in ("idle_share.flat", "idle_share.ivf",
-                 "host_ms_per_batch.flat", "flat_scan_roofline"):
+    for name in ("idle_share.flat", "idle_share.ivf", "flat_scan_roofline"):
         assert _read(name, r) is None
 
 

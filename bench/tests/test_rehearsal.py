@@ -80,6 +80,14 @@ def _break_answers(monkeypatch, how):
                 a[half:] = a[:ids.shape[0] - half]
                 out[key] = a
             return out
+        elif how == "row_moved_to_another_slot":
+            # a partitioned answer's best row of category 0 shown again in
+            # category 1's slot, its score and validity with it
+            for key in ("ids", "sim", "valid"):
+                a = np.array(out[key])
+                a[:, 1, 0] = a[:, 0, 0]
+                out[key] = a
+            return out
         out["ids"] = ids
         return out
 
@@ -94,9 +102,40 @@ def test_a_broken_served_path_is_not_correct(cell, how, monkeypatch):
     assert result["correct"] is False, result["checks"]
 
 
-def test_files_dropped_in_are_found_by_name(tmp_path):
-    """A copy of the benchmark gains a configuration, a traffic mix, a
-    metric and a cell by new files and a BENCHMARK.json entry alone."""
+def test_the_check_interface_reads_as_filtered_knn_did():
+    """On one rehearsal record, ``compare_run`` through the harness gives
+    the numbers that ``compare`` gives on the arguments the harness used
+    to build for it, and the answers keep Q1's shapes and types."""
+    import jax.numpy as jnp
+    cell = harness.load_cell(CELLS[0])
+    shrink(cell)
+    clock = harness.CompileClock()
+    world = harness.build_world(cell, SEED, 1.0, clock)
+    tl = harness.drive(world, cell, 1.0, clock)
+    numbers, _ = harness.check(world, cell, tl, SEED)
+    spec = cell.traffic["check"]
+    k = spec["k"]
+    for key, dtype in (("ids", np.int32), ("sim", np.float32),
+                       ("valid", bool)):
+        assert tl.answers[key].shape == (tl.ok.size, k)
+        assert tl.answers[key].dtype == dtype
+    pick = harness._sample(tl, spec["sample"], SEED)
+    req = world.requests
+    qv = np.stack([req.binds[i][spec["vector_bind"]] for i in pick])
+    bounds = np.asarray([req.binds[i][spec["bound_bind"]] for i in pick],
+                        np.float32)
+    col = world.dataset.columns[spec["column"]]
+    served = {key: tl.answers[key][pick] for key in ("ids", "sim", "valid")}
+    before = harness.load_module("checks", "filtered_knn").compare(
+        world.dataset.corpus, col, jnp.asarray(col), qv, bounds, served, k,
+        cell.config["guarantee"])
+    assert {n: v for n, v in numbers.items() if n != "failed"} == before
+
+
+def _small_checkout(tmp_path, traffic: str, mix: dict):
+    """A copy of the benchmark that gains the configuration
+    ``laion_small_flat``, the mix ``traffic`` and their cell by new files
+    and a BENCHMARK.json entry alone; returns (root, spec, cell name)."""
     root = tmp_path / "checkout"
     root.mkdir()
     shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), root)
@@ -107,38 +146,89 @@ def test_files_dropped_in_are_found_by_name(tmp_path):
     cfg["rows"] = 2500
     (root / "bench/configs/laion_small_flat.json").write_text(
         json.dumps(cfg))
-    tr = json.loads((root / "bench/traffic/q1_closed128.json").read_text())
-    tr["clients"] = 8
-    tr["server"]["max_batch"] = 4
-    tr["warm_batches"] = [4]
-    tr["check"]["sample"] = 32
-    (root / "bench/traffic/q1_closed8.json").write_text(json.dumps(tr))
-    (root / "bench/metrics/drains_per_s.py").write_text(
-        "def read(record):\n"
-        "    tl = record.timeline\n"
-        "    return len(tl.drains) / (tl.end - tl.t0)\n")
+    (root / f"bench/traffic/{traffic}.json").write_text(json.dumps(mix))
+    name = f"laion_small_flat.{traffic}"
     spec = json.loads((root / "BENCHMARK.json").read_text())
     spec["configs"].append({"name": "laion_small_flat", "source": "x",
                             "file": "bench/configs/laion_small_flat.json",
                             "reduced": ["rows"], "why": "test"})
-    spec["workloads"].append({"name": "laion_small_flat.q1_closed8",
-                              "config": "laion_small_flat",
-                              "traffic": "q1_closed8", "chips": 1,
+    spec["workloads"].append({"name": name, "config": "laion_small_flat",
+                              "traffic": traffic, "chips": 1,
                               "why": "test"})
+    (root / "BENCHMARK.json").write_text(json.dumps(spec))
+    return root, spec, name
+
+
+def tiny_dim(cell):
+    cell.config["modes"] = 8
+
+
+def test_files_dropped_in_are_found_by_name(tmp_path):
+    """A copy of the benchmark gains a configuration, a traffic mix, a
+    metric and a cell by new files and a BENCHMARK.json entry alone."""
+    with open(os.path.join(ROOT, "bench/traffic/q1_closed128.json")) as f:
+        tr = json.load(f)
+    tr["clients"] = 8
+    tr["server"]["max_batch"] = 4
+    tr["warm_batches"] = [4]
+    tr["check"]["sample"] = 32
+    root, spec, name = _small_checkout(tmp_path, "q1_closed8", tr)
+    (root / "bench/metrics/drains_per_s.py").write_text(
+        "def read(record):\n"
+        "    tl = record.timeline\n"
+        "    return len(tl.drains) / (tl.end - tl.t0)\n")
     spec["end_to_end"].append({"name": "drains_per_s", "unit": "1/s",
                                "better": "higher", "bound": 0.05,
                                "source": "host_clock",
-                               "workloads": ["laion_small_flat.q1_closed8"]})
+                               "workloads": [name]})
     (root / "BENCHMARK.json").write_text(json.dumps(spec))
 
-    def tiny_dim(cell):
-        cell.config["modes"] = 8
-
-    result = run("laion_small_flat.q1_closed8", root=str(root),
-                 adjust=tiny_dim)
+    result = run(name, root=str(root), adjust=tiny_dim)
     assert result["correct"] is True, result["checks"]
     assert result["metrics"]["drains_per_s"]["value"] > 0
     assert result["metrics"]["drains_per_s"]["unit"] == "1/s"
+
+
+# CHASE's Q5 (the paper's Fig. 8), K = 10: per calorie level, the ten rows
+# nearest the query among those within the radius and not of cuisine ex
+SQL_Q5 = (
+    "SELECT qid, category FROM ("
+    " SELECT sample_id AS qid, calorie_level AS category,"
+    " RANK() OVER (PARTITION BY calorie_level"
+    "   ORDER BY DISTANCE(embedding, ${qv})) AS rank"
+    " FROM recipes"
+    " WHERE DISTANCE(embedding, ${qv}) <= ${r} AND cuisine <> ${ex}"
+    ") AS ranked WHERE ranked.rank <= 10")
+
+Q5_MIX = {
+    "sql": SQL_Q5,
+    "binds": {"qv": {"kind": "query_vector"},
+              # on the toy corpus about 10 rows a category qualify, so
+              # some slots fill and some come short
+              "r": {"kind": "constant", "value": 0.935},
+              "ex": {"kind": "constant", "value": 3}},
+    "loop": "closed", "clients": 8, "pool_per_s": 4000,
+    "server": {"max_batch": 4, "max_wait_ms": 2.0},
+    "warm_batches": [4],
+    "check": {"module": "partition_topk", "k": 10, "sample": 32,
+              "vector_bind": "qv", "radius_bind": "r", "exclude_bind": "ex",
+              "exclude_column": "cuisine",
+              "partition_column": "calorie_level"}}
+
+
+@pytest.mark.parametrize("how", [None, "answer_altered",
+                                 "half_batch_left_out",
+                                 "row_moved_to_another_slot"])
+def test_a_q5_cell_dropped_in(tmp_path, monkeypatch, how):
+    """A category-partition top-k cell comes from new files alone: its
+    answers of shape (C, K), constant binds and its own check module; it
+    is correct, and each broken served path makes it not correct."""
+    root, _, name = _small_checkout(tmp_path, "q5_closed8", Q5_MIX)
+    if how is not None:
+        _break_answers(monkeypatch, how)
+    result = run(name, root=str(root), adjust=tiny_dim)
+    assert result["correct"] is (how is None), result["checks"]
+    assert result["attempted"] > 0 and result["failed"] == 0
 
 
 def _command(cwd, env_extra=None):

@@ -1,12 +1,15 @@
 """The one traffic generator: requests drawn from the configuration's
 data seed, ordered by the run's seed, with Poisson arrivals in an open
-loop."""
+loop and in the on periods of an on/off one; constant binds; and the
+committed mixes' requests pinned."""
+import hashlib
 import types
 
 import numpy as np
 import pytest
 
 import harness
+from test_rehearsal import CELLS, SPEC
 
 traffic = harness.own("traffic")
 
@@ -74,3 +77,80 @@ def test_open_loop_gaps_are_exponential():
     # the arrival count per second wanders as a Poisson count does
     per_s = np.bincount(req.offsets.astype(int), minlength=int(seconds))
     assert per_s.var() / per_s.mean() == pytest.approx(1.0, abs=0.5)
+
+
+def test_a_constant_bind_draws_nothing():
+    ds = _dataset()
+    mix = {**_open(), "binds": {**MIX["binds"],
+                                "r": {"kind": "constant", "value": 0.5},
+                                "ex": {"kind": "constant", "value": 3}}}
+    a = traffic.generate(_open(), ds, 5, 11, 4.0)
+    b = traffic.generate(mix, ds, 5, 11, 4.0)
+    assert [_key(x) for x in a.binds] == [_key(x) for x in b.binds]
+    np.testing.assert_array_equal(a.offsets, b.offsets)
+    assert {x["r"].dtype for x in b.binds} == {np.dtype(np.float32)}
+    assert {x["ex"].dtype for x in b.binds} == {np.dtype(np.int32)}
+    assert {float(x["r"]) for x in b.binds} == {0.5}
+    assert {int(x["ex"]) for x in b.binds} == {3}
+    with pytest.raises(ValueError, match="a number"):
+        traffic.generate({**mix, "binds": {"x": {"kind": "constant",
+                                                 "value": "3"}}},
+                         ds, 5, 11, 1.0)
+
+
+def test_onoff_arrivals_come_only_while_on_at_the_mean_rate():
+    ds = _dataset()
+    rate, on_s, off_s, seconds = 200.0, 0.5, 1.5, 200.0
+    mix = {**MIX, "loop": "onoff", "rate_per_s": rate, "on_s": on_s,
+           "off_s": off_s}
+    req = traffic.generate(mix, ds, 5, 9, seconds)
+    assert len(req.binds) == pytest.approx(rate * seconds, rel=0.05)
+    assert np.all(np.diff(req.offsets) >= 0)
+    assert req.offsets.max() < seconds
+    assert np.all(req.offsets % (on_s + off_s) < on_s)
+    # inside an on period they come at the peak rate, four times the mean
+    gaps = np.diff(req.offsets)
+    within = gaps[gaps < off_s]
+    assert 1 / within.mean() == pytest.approx(
+        rate * (on_s + off_s) / on_s, rel=0.05)
+
+
+# sha256 of the requests that each committed mix file makes at the CPU
+# rehearsal's toy sizes (test_rehearsal.shrink, 1.5 s, its seed), as the
+# generator drew them before the constant bind and the on/off loop came
+MIX_DIGESTS = {
+    "q1_closed128": (
+        6016,
+        "66e4bd41ef4a9fd5a0b1fb4c2d3c44660fca749276e8ca87882ac3ccf8cd48f7"),
+    "q1_poisson": (
+        227,
+        "1403947af6c17148d0ba34fab74d6f56d5fa3697f62ca59fb11dbf6e8835d6c0"),
+    "q1_serial": (
+        6016,
+        "66e4bd41ef4a9fd5a0b1fb4c2d3c44660fca749276e8ca87882ac3ccf8cd48f7"),
+}
+
+
+def _digest(req) -> str:
+    h = hashlib.sha256()
+    for b in req.binds:
+        for name in sorted(b):
+            h.update(name.encode())
+            h.update(np.ascontiguousarray(b[name]).tobytes())
+    if req.offsets is not None:
+        h.update(np.ascontiguousarray(req.offsets).tobytes())
+    h.update(repr(req.clients).encode())
+    return h.hexdigest()
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_committed_mixes_draw_what_they_drew(cell):
+    from test_rehearsal import SEED, shrink
+    c = harness.load_cell(cell)
+    shrink(c)
+    ds = harness.load_module("datasets", c.config["dataset"]).build(
+        c.config, harness.seed_key(c.config["data_seed"]))
+    req = traffic.generate(c.traffic, ds, c.config["data_seed"], SEED, 1.5)
+    traffic_name = next(w["traffic"] for w in SPEC["workloads"]
+                        if w["name"] == cell)
+    assert (len(req.binds), _digest(req)) == MIX_DIGESTS[traffic_name]
